@@ -7,8 +7,9 @@ transfers) coordinated by a conservative lookahead window, while the
 coordinator replays epoch starts and update arrivals in global time
 order: cohort-vectorized vmap training keeps the JAX cost at
 O(replicas), and whole flush-windows of FedAsync updates fold into the
-global model in ONE fedavg_agg kernel dispatch instead of one tree-map
-per update. Per-round metrics are bit-identical for any shard count.
+global model in ONE exact int64 host fold (``coeff_fold_tree``) instead
+of one tree-map per update. Per-round metrics are bit-identical for any
+shard count.
 
 With FLEET_SIM_WORKERS set, the shard-group worker processes own the
 cohort XLA training too (the coordinator only aggregates and
@@ -58,7 +59,7 @@ def main():
                                         rate_per_round=0.05, seed=0))
 
     # 3. FedAsync aggregation: updates buffer per flush window and mix in
-    #    with one batched kernel dispatch, discounted by staleness —
+    #    with one exact host fold (int64 numpy), discounted by staleness —
     #    mid-migration devices contribute late instead of stalling a
     #    barrier. Staleness counts aggregator versions, and every fleet
     #    round applies ~NUM_CLIENTS of them, so the hinge tolerates up to

@@ -1,26 +1,31 @@
 """Pallas TPU flash attention (forward).
 
-Grid: (B·KV heads, S/BQ query blocks). Each program instance holds one
+Grid: (B·H heads, S/BQ query blocks). Each program instance holds one
 (BQ, hd) query tile in VMEM and loops over T/BK key/value tiles with the
 online-softmax recurrence, so VMEM never sees an (S, T) logit matrix.
-GQA is handled by loading one KV head per group of ``rep`` query rows:
-the q tile is (rep·BQ, hd) flattened so the MXU matmul dims stay
-hardware-aligned (BQ, BK, hd multiples of 128 where the model allows).
+GQA (``rep`` query heads per KV head) repeats each KV head ``rep`` times
+and folds the query heads into the grid's batch axis, so every row keeps
+its own causal position (BQ, BK, hd multiples of 128 where the model
+allows keep the MXU dims hardware-aligned).
 
 Masking (causal / sliding window) is applied from block-relative
 positions; fully-masked key blocks are skipped by clamping the kv loop
 bound per query block (causal: kv blocks beyond the diagonal never run).
 
 Validated in interpret mode against ``ref.attention_ref`` (CPU); the TPU
-path is the same kernel with interpret=False.
+path is the same kernel compiled. ``interpret=None`` (the default)
+auto-detects the platform like ``fedavg_agg.resolve_interpret``.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.fedavg_agg.fedavg_agg import resolve_interpret
 
 BIG_NEG = -2.3819763e38
 
@@ -41,10 +46,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, bq: int, bk: int,
 
     def body(kv_i, carry):
         m, l, acc = carry
-        k = pl.load(k_ref, (pl.dslice(kv_i * bk, bk), slice(None))
-                    ).astype(jnp.float32)              # (BK, hd)
-        v = pl.load(v_ref, (pl.dslice(kv_i * bk, bk), slice(None))
-                    ).astype(jnp.float32)
+        k = k_ref[pl.ds(kv_i * bk, bk), :].astype(jnp.float32)  # (BK, hd)
+        v = v_ref[pl.ds(kv_i * bk, bk), :].astype(jnp.float32)
         lg = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (BQ, BK)
         if softcap > 0:
             lg = softcap * jnp.tanh(lg / softcap)
@@ -76,7 +79,7 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
                         causal: bool = True, window: int = 0,
                         softcap: float = 0.0, block_q: int = 128,
                         block_k: int = 128,
-                        interpret: bool = True) -> jax.Array:
+                        interpret: Optional[bool] = None) -> jax.Array:
     """q: (B, H, S, hd); k/v: (B, KV, T, hd). Returns (B, H, S, hd).
 
     S must divide by block_q and T by block_k (pad upstream if needed).
@@ -88,12 +91,8 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
     bk = min(block_k, T)
     assert S % bq == 0 and T % bk == 0, (S, T, bq, bk)
 
-    # flatten GQA: one KV head serves `rep` query heads -> fold rep into S
-    qf = q.reshape(B, KV, rep * S, hd)
-
-    grid = (B * KV, (rep * S) // bq)
-    # NOTE: with rep>1 the causal mask needs per-row positions; simplest
-    # exact handling folds rep into the batch axis instead when rep>1.
+    # GQA: the causal mask needs per-row positions, so rep > 1 folds the
+    # query heads into the batch axis and repeats each KV head rep times
     if rep > 1:
         qf = q.reshape(B * H, 1, S, hd)
         kf = jnp.repeat(k, rep, axis=1).reshape(B * H, 1, T, hd)
@@ -125,5 +124,5 @@ def _call(qf, kf, vf, bq, bk, causal, window, softcap, hd, interpret):
         out_specs=pl.BlockSpec((None, None, bq, hd),
                                lambda b, i: (b, 0, i, 0)),
         out_shape=jax.ShapeDtypeStruct(qf.shape, qf.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qf, kf, vf).reshape(BH, 1, S, hd)

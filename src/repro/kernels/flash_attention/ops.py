@@ -1,7 +1,8 @@
 """Jit'd wrapper for the flash-attention kernel.
 
 ``flash_attention`` pads S/T to block multiples, dispatches to the Pallas
-kernel (interpret=True on CPU, compiled on TPU), and is differentiable:
+kernel (interpreted on CPU, compiled on TPU: ``interpret=None``
+auto-detects), and is differentiable:
 the backward pass recomputes attention via the pure-jnp oracle (standard
 flash recompute strategy — O(S·BK) memory both ways).
 """
@@ -28,7 +29,7 @@ def _pad_to(x, mult, axis):
 @functools.partial(jax.custom_vjp,
                    nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def flash_attention(q, k, v, causal=True, window=0, softcap=0.0,
-                    block_q=128, block_k=128, interpret=True):
+                    block_q=128, block_k=128, interpret=None):
     """q: (B, H, S, hd); k/v: (B, KV, T, hd) -> (B, H, S, hd)."""
     S, T = q.shape[2], k.shape[2]
     qp, ps = _pad_to(q, block_q, 2)

@@ -25,7 +25,11 @@ compile the kernel. (The tree-level ops layer goes one step further and
 routes CPU to a pure-numpy reference.)
 
 Grid: (ceil(n / (ROWS·BLOCK)),); tiles are (ROWS, BLOCK) with BLOCK=1024
-lanes (128-aligned) and ROWS=8 sublanes.
+lanes (128-aligned) and ROWS=8 sublanes. Inside the kernels the scales
+are a (ROWS, 1) column: Mosaic refuses a rank-1 (ROWS,) block, whose
+length must be a multiple of 128 or the whole array. The wrappers
+reshape at the boundary, so callers (and the FFLY wire format) still
+see ceil(n/BLOCK) flat scales.
 """
 from __future__ import annotations
 
@@ -45,31 +49,32 @@ BLOCK = 1024
 ROWS = 8
 
 
-def _quant_kernel(x_ref, q_ref, s_ref):
-    x = x_ref[...].astype(jnp.float32)                  # (ROWS, BLOCK)
-    scale = jnp.maximum(jnp.max(jnp.abs(x), axis=1) / 127.0, 1e-12)
-    q = jnp.clip(jnp.round(x / scale[:, None]), -127, 127)
+def _quantize_tile(x, q_ref, s_ref):
+    scale = jnp.maximum(jnp.max(jnp.abs(x), axis=1, keepdims=True) / 127.0,
+                        1e-12)                           # (ROWS, 1)
+    q = jnp.clip(jnp.round(x / scale), -127, 127)
     q_ref[...] = q.astype(jnp.int8)
     s_ref[...] = scale
+
+
+def _quant_kernel(x_ref, q_ref, s_ref):
+    _quantize_tile(x_ref[...].astype(jnp.float32), q_ref, s_ref)
 
 
 def _quant_res_kernel(x_ref, b_ref, q_ref, s_ref):
     """Residual mode: quantize x - base in the same VMEM pass."""
-    r = x_ref[...].astype(jnp.float32) - b_ref[...].astype(jnp.float32)
-    scale = jnp.maximum(jnp.max(jnp.abs(r), axis=1) / 127.0, 1e-12)
-    q = jnp.clip(jnp.round(r / scale[:, None]), -127, 127)
-    q_ref[...] = q.astype(jnp.int8)
-    s_ref[...] = scale
+    _quantize_tile(x_ref[...].astype(jnp.float32)
+                   - b_ref[...].astype(jnp.float32), q_ref, s_ref)
 
 
 def _dequant_kernel(q_ref, s_ref, x_ref):
     q = q_ref[...].astype(jnp.float32)
-    x_ref[...] = (q * s_ref[...][:, None]).astype(x_ref.dtype)
+    x_ref[...] = (q * s_ref[...]).astype(x_ref.dtype)
 
 
 def _dequant_res_kernel(q_ref, s_ref, b_ref, x_ref):
     q = q_ref[...].astype(jnp.float32)
-    x_ref[...] = (q * s_ref[...][:, None]
+    x_ref[...] = (q * s_ref[...]
                   + b_ref[...].astype(jnp.float32)).astype(x_ref.dtype)
 
 
@@ -105,12 +110,12 @@ def quantize_packed(x: jax.Array, base: Optional[jax.Array] = None, *,
         grid=(rt // ROWS,),
         in_specs=specs,
         out_specs=[pl.BlockSpec((ROWS, BLOCK), lambda i: (i, 0)),
-                   pl.BlockSpec((ROWS,), lambda i: (i,))],
+                   pl.BlockSpec((ROWS, 1), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((rt, BLOCK), jnp.int8),
-                   jax.ShapeDtypeStruct((rt,), jnp.float32)],
+                   jax.ShapeDtypeStruct((rt, 1), jnp.float32)],
         interpret=resolve_interpret(interpret),
     )(*args)
-    return q.reshape(-1), s
+    return q.reshape(-1), s.reshape(-1)
 
 
 def dequantize(q: jax.Array, scales: jax.Array, n: int, dtype=jnp.float32,
@@ -130,9 +135,10 @@ def dequantize_packed(q: jax.Array, scales: jax.Array, n: int,
     qp = _pad_rows(q)
     rt = qp.shape[0]
     scales = jnp.pad(scales.astype(jnp.float32),
-                     (0, rt - scales.shape[0]), constant_values=1.0)
+                     (0, rt - scales.shape[0]),
+                     constant_values=1.0).reshape(rt, 1)
     specs = [pl.BlockSpec((ROWS, BLOCK), lambda i: (i, 0)),
-             pl.BlockSpec((ROWS,), lambda i: (i,))]
+             pl.BlockSpec((ROWS, 1), lambda i: (i, 0))]
     args = [qp, scales]
     kernel = _dequant_kernel
     if base is not None:

@@ -12,16 +12,20 @@ recurrence for the MXU/VPU instead:
   operands. K = V = 64 (RWKV head size), so a (64, 64) fp32 state tile
   fits VMEM comfortably alongside the (CHUNK, 64) operand tiles.
 
-Validated in interpret mode against ``ref.wkv6_ref``.
+Validated in interpret mode against ``ref.wkv6_ref``; ``interpret=None``
+(the default) auto-detects the platform like ``fedavg_agg``.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.fedavg_agg.fedavg_agg import resolve_interpret
 
 
 def _wkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, s_final_ref,
@@ -53,7 +57,7 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, s_final_ref,
 
 
 def wkv6_chunked(r, k, v, w, u, *, chunk: int = 64,
-                 interpret: bool = True):
+                 interpret: Optional[bool] = None):
     """r/k/w: (B, T, H, K); v: (B, T, H, V); u: (H, K).
     Returns (y (B, T, H, V), final state (B, H, K, V))."""
     B, T, H, K = r.shape
@@ -89,7 +93,7 @@ def wkv6_chunked(r, k, v, w, u, *, chunk: int = 64,
             jax.ShapeDtypeStruct((B * H, K, V), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((K, V), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(rf, kf, vf, wf, uf)
     return (y.reshape(B, H, T, V).swapaxes(1, 2),
             s_final.reshape(B, H, K, V))

@@ -34,6 +34,7 @@ from repro.data.loader import Batcher
 from repro.data.partition import balanced, by_fraction
 from repro.launch import sharding as sh
 from repro.launch import steps as steps_lib
+from repro.launch.compile_cache import enable_compilation_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models.registry import build_model, get_config, make_reduced
 from repro.obs import log as obs_log
@@ -164,6 +165,7 @@ def main() -> None:
     obs_log.add_verbosity_flags(ap)
     args = ap.parse_args()
     obs_log.setup(verbosity=obs_log.verbosity_from_args(args))
+    enable_compilation_cache()
     if args.mode == "testbed":
         run_testbed(args)
     else:

@@ -199,7 +199,7 @@ class AsyncAggregator:
 
     def flush_batch(self, updates: Sequence[Tuple[Params, float, int]]
                     ) -> List[float]:
-        """Fold a whole flush window of updates in ONE kernel dispatch.
+        """Fold a whole flush window of updates in ONE host fold.
 
         ``updates`` is an *arrival-ordered* list of (tree, weight,
         staleness). Sequential mixing
@@ -212,7 +212,7 @@ class AsyncAggregator:
             b_i = a_i * prod_{j>i} (1 - a_j)
 
         so folding the effective coefficients b into one
-        ``fedavg_mix_tree`` call is algebraically identical to E
+        ``coeff_fold_tree`` call is algebraically identical to E
         sequential submits (fp-accumulation order aside). Updates that
         share a tree object (cohort replicas shared by many clients) are
         grouped, so the fold axis is the number of *distinct* trees, not

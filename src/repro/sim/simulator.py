@@ -15,7 +15,7 @@ Architecture (this is the sharded rewrite — see README.md):
   mailbox    — the group mesh: pipe/socket transports, the control
                plane, and the shared coordinator drive loop
   async_agg  — sync FedAvg barrier or FedAsync *batched* staleness-
-               weighted mixing (one fedavg_agg kernel dispatch per flush)
+               weighted mixing (one exact host fold per flush)
   metrics    — per-round JSON records
 
 ``FleetSimulator`` is the coordinator: it partitions the edges over
@@ -37,12 +37,13 @@ consumes the identical broadcast bytes wherever it runs).
 Aggregation: in async mode arriving updates are *buffered* and flushed
 on a fixed simulated-time grid (``flush_interval_s``, default = the
 fleet's fastest uncongested batch time): each flush folds the whole
-window into the global model with one ``fedavg_mix_tree`` kernel
-dispatch, sequential-equivalent effective coefficients, and staleness
-counted against the flush timeline. In sync mode the round barrier
-commits a dataset-size-weighted average (one stacked ``fedavg_tree``
-dispatch); an empty round carries the global forward and is recorded as
-skipped instead of crashing.
+window into the global model with one ``coeff_fold_tree`` call, an exact
+int64 fixed-point fold in host numpy, with sequential-equivalent
+effective coefficients and staleness counted against the flush
+timeline. In sync mode the round barrier commits a dataset-size-weighted
+average through the same host fold; an empty round carries the global
+forward and is recorded as skipped instead of crashing. No Pallas kernel
+runs on this path.
 """
 from __future__ import annotations
 
@@ -53,6 +54,7 @@ import time
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from repro.core.checkpoint import EdgeCheckpoint
@@ -132,7 +134,9 @@ class FleetSimulator:
     XLA training into the group processes (each group owns the cohorts
     whose clients it hosts), and both require ``measure_pack=False`` —
     group timing engines price migrations from the cached cohort
-    tables."""
+    tables. On a TPU backend both are refused at construction: the chip
+    belongs to this process, so only the serial executor can train on
+    it."""
 
     def __init__(self, fleet: Fleet, edges: Sequence[SimEdge], *,
                  trace: Optional[MobilityTrace] = None,
@@ -210,6 +214,16 @@ class FleetSimulator:
             if workers is not None:
                 raise ValueError("hosts and workers are mutually "
                                  "exclusive (sockets vs pipes)")
+        if (workers is not None or hosts is not None) \
+                and jax.default_backend() == "tpu":
+            # the Fleet already initialised its model here, so this
+            # process holds the chip; sending the groups to the CPU
+            # would hide the device instead of using it
+            raise ValueError(
+                f"{'workers' if workers is not None else 'hosts'}= spawns "
+                "shard-group processes that train with JAX, but a TPU "
+                "chip belongs to one process. Run on the serial executor "
+                "(leave workers and hosts unset) on a TPU backend")
         self.fleet = fleet
         self.edge_order = [e.edge_id for e in edges]
         self.edges: Dict[str, SimEdge] = {e.edge_id: e for e in edges}
@@ -507,7 +521,7 @@ class FleetSimulator:
         self._trainer.request(cohort_key, epoch)
 
     def _fire_flush(self, t: float):
-        """Apply all buffered updates (arrival < t) in one kernel call."""
+        """Apply all buffered updates (arrival < t) in one host fold."""
         if not self._buffer:
             return
         base = self.agg.version

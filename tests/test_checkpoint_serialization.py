@@ -80,7 +80,11 @@ if HAS_HYPOTHESIS:
         back = ser.unpack_pytree(
             ser.pack_pytree(tree, "delta", base=base, base_version="t"),
             base=base)["w"]
-        bound = np.abs(x - b).max() / 127.0 * 0.51 + 1e-7
+        # the residual x - b and the restore b + residual are both
+        # rounded in float32 at |x| up to ~12, where one rounding step
+        # (~1e-6) dwarfs the quantization error of a small drift
+        rounding = 2 * np.spacing(np.abs(x).max())
+        bound = np.abs(x - b).max() / 127.0 * 0.51 + 1e-7 + rounding
         assert np.max(np.abs(back - x)) <= bound
 
 

@@ -91,6 +91,27 @@ def test_workers_require_skipping_real_pack():
                        measure_pack=True)
 
 
+@pytest.mark.parametrize("executor", ["workers", "hosts"])
+def test_mesh_executors_refused_on_tpu(executor, monkeypatch):
+    """A TPU belongs to one process: on a TPU backend the pipe and the
+    socket mesh are refused at construction, before any process exists,
+    and the message names the serial executor."""
+    import multiprocessing
+    edges = make_edges(2)
+    from repro.sim.fleet import make_fleet_specs
+    specs = make_fleet_specs(4, [e.edge_id for e in edges])
+    fleet = Fleet(VGG5(), sgd(momentum=0.9), specs, split_point=2,
+                  lr_schedule=constant(0.01), max_replicas=2, seed=0)
+    children = set(multiprocessing.active_children())
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="serial executor"):
+        FleetSimulator(fleet, edges, shards=2, measure_pack=False,
+                       **{executor: 2})
+    assert set(multiprocessing.active_children()) == children
+    # the serial executor stays available on the chip
+    FleetSimulator(fleet, edges, shards=2, measure_pack=False)
+
+
 # -- congestion re-pricing ----------------------------------------------------
 
 def test_inflight_batch_reprice_math():
