@@ -1,0 +1,214 @@
+"""What every driver's cell shares: what it keeps from the window for the
+comparison, and the numbers it turns that into.
+
+A driver fills in, while its system runs:
+
+``capture``  the first three steps of each client (``steps.StepCapture``).
+``fold``     one fold of the window: ``{"trees", "weights", "out"}``, a
+             weighted mean (FedAvg).
+``migs``     a reservoir sample of the window's migrations
+             (``sample_migration``), each with the packed quantize's
+             inputs and outputs where the codec ran it.
+
+``numbers`` compares what the program produced; ``variants`` puts the
+control (the reference one precision step down) or a planted fault in
+the program's place, one part at a time, for the readings the limits are
+set from.
+"""
+from __future__ import annotations
+
+import math
+from typing import Any, Callable, Dict, List, Optional
+
+import numpy as np
+
+import check
+from steps import StepCapture, program_readings, reference_readings
+
+SAMPLED_MIGRATIONS = 8
+CHECKPOINT_PARTS = ("server_params", "optimizer_state", "last_grads",
+                    "scalars")
+#: the training comparison's two references: the configuration's stated
+#: precision (names as they are) and float32 as written (``_f32``)
+PRECISIONS = (("default", ""), ("highest", "_f32"))
+#: what ``variants`` can put in the program's place
+VARIANTS = ("control_step", "control_fold", "control_codec", "half_batch")
+
+
+def _leaves(tree) -> List[np.ndarray]:
+    import jax
+    return [np.asarray(x) for x in jax.tree.leaves(tree)]
+
+
+def _bf16(a) -> np.ndarray:
+    import ml_dtypes
+    return np.asarray(a, ml_dtypes.bfloat16).astype(np.float64)
+
+
+class CellBase:
+    def __init__(self, config: Dict[str, Any], traffic: Dict[str, Any],
+                 seed: int, spans, tick: Callable[[int], None] = None,
+                 reference=None):
+        self.config, self.traffic, self.seed = config, traffic, seed
+        self.spans = spans
+        self.tick = tick or (lambda n: None)
+        self.ref = reference
+        self.step_program = config["step_program"]
+        self.rng = np.random.default_rng([seed, 5])
+        self.capture = StepCapture(self._unpack)
+        self.migs: List[Dict[str, Any]] = []
+        self.n_migs_seen = 0
+        self.fold: Optional[Dict[str, Any]] = None
+
+    @staticmethod
+    def _unpack(args, outs):
+        """A split train step's (dev, srv, dev_opt, srv_opt, batch, lr)
+        and its (dev, srv, dev_opt, srv_opt, loss, ...)."""
+        return {"params": (args[0], args[1]), "batch": args[4],
+                "new_params": (outs[0], outs[1]),
+                "new_mu": (outs[2]["mu"], outs[3]["mu"]), "loss": outs[4]}
+
+    def check_layers(self) -> None:
+        from repro.models.vgg import VGG5_LAYERS
+        want = [tuple(l) for l in self.config["layers"]]
+        have = [(k, *s) for k, s in VGG5_LAYERS]
+        if want != have:
+            raise ValueError(f"configuration layers {want} are not the "
+                             f"program's VGG-5 {have}")
+
+    def sample_migration(self, ckpt, base, restored, quant=None) -> None:
+        """``quant``: (leaves, bases, codes, scales) of the packed
+        quantize this migration ran, if it ran one."""
+        self.n_migs_seen += 1
+        rec = {"sent": ckpt, "base": base, "restored": restored,
+               "quant": quant}
+        if len(self.migs) < SAMPLED_MIGRATIONS:
+            self.migs.append(rec)
+            return
+        j = int(self.rng.integers(0, self.n_migs_seen))
+        if j < SAMPLED_MIGRATIONS:
+            self.migs[j] = rec
+
+    # -- the numbers --------------------------------------------------------
+
+    def _streams(self):
+        return [self.capture.streams[k] for k in sorted(self.capture.streams)]
+
+    def training_readings(self, mode: str = "program") -> Dict[str, float]:
+        """Readings of the program (``program``), or of the reference put
+        in its place in bfloat16 (``control``) or over half of each batch
+        (``half_batch``), against the float32 reference at the stated
+        precision and at ``HIGHEST``."""
+        streams = self._streams()
+        out: Dict[str, float] = {}
+        for precision, suffix in PRECISIONS:
+            ref = reference_readings(self.ref, self.config, self.seed,
+                                     streams, precision)
+            if mode == "program":
+                got = program_readings(streams)
+            else:
+                got = reference_readings(
+                    self.ref, self.config, self.seed, streams, precision,
+                    control=mode == "control",
+                    half_batch=mode == "half_batch")
+            out.update(check.training_numbers(got, ref, suffix))
+        return out
+
+    def fold_number(self, control: bool = False) -> float:
+        """The committed fold against a float64 numpy fold of the same
+        inputs; ``control`` folds bfloat16-rounded inputs instead."""
+        f = self.fold
+        if f is None:
+            return math.inf
+        trees = [_leaves(t) for t in f["trees"]]
+        ref = check.fedavg_ref(trees, f["weights"])
+        if control:
+            out = [_bf16(x) for x in check.fedavg_ref(
+                [[_bf16(x) for x in t] for t in trees], f["weights"])]
+        else:
+            out = _leaves(f["out"])
+        floats = [i for i, x in enumerate(ref) if x.dtype.kind == "f"]
+        return check.fold_err([out[i] for i in floats],
+                              [ref[i] for i in floats])
+
+    def _mig_leaves(self, m):
+        import jax
+        sent, rest = m["sent"].to_tree(), m["restored"].to_tree()
+        s_l, r_l, b_l = [], [], []
+        for k in CHECKPOINT_PARTS:
+            if k not in sent:
+                continue
+            ls = jax.tree.leaves(sent[k])
+            s_l += ls
+            r_l += jax.tree.leaves(rest[k])
+            if k == "server_params" and m["base"] is not None:
+                b_l += jax.tree.leaves(m["base"]["server_params"])
+            else:
+                b_l += [None] * len(ls)
+        return s_l, r_l, b_l
+
+    def codec_number(self, control: bool = False) -> Dict[str, float]:
+        """``raw``: elements not restored bit for bit. ``int8``/``delta``:
+        the worst restore error in half quantization steps and, where the
+        migration ran the packed quantize, its codes and scales against
+        the reference. ``control`` restores from, and quantizes to, int4
+        codes instead (raw has no lower step)."""
+        codec = self.traffic["codec"]
+        name = "raw_mismatch" if codec == "raw" else "codec_err"
+        if control and codec == "raw":
+            return {}
+        if not self.migs:
+            return {name: math.inf}
+        out = {name: 0.0}
+        for m in self.migs:
+            s, r, b = self._mig_leaves(m)
+            if codec == "raw":
+                out[name] += check.raw_mismatch(s, r)
+                continue
+            if control:
+                r = check.requantize(s, b, codec, bits=4)
+            out[name] = max(out[name], check.codec_err(s, r, b, codec))
+            if m["quant"] is not None:
+                leaves, bases, codes, scales = m["quant"]
+                if control:
+                    codes, scales = check.quantize_blocks(
+                        check.pack(leaves, leaves),
+                        None if bases is None else check.pack(bases, leaves),
+                        qmax=7)
+                for k, v in check.kernel_numbers(leaves, bases, codes,
+                                                 scales).items():
+                    out[k] = max(out.get(k, 0), v)
+        out[name] = float(out[name])
+        return out
+
+    def numbers(self) -> Dict[str, float]:
+        out = self.training_readings("program")
+        out["fold_err"] = self.fold_number()
+        out.update(self.codec_number())
+        return out
+
+    def variants(self, names, program: Dict[str, float]
+                 ) -> Dict[str, Dict[str, float]]:
+        """For each name in ``VARIANTS``: the program's numbers with one
+        part put in its place. ``control_step``: the training steps in
+        bfloat16; ``control_fold``: the fold of bfloat16-rounded inputs;
+        ``control_codec``: int4 codes; ``half_batch``: the training steps
+        over the first half of each batch."""
+        out = {}
+        for name in names:
+            nums = dict(program)
+            if name == "control_step":
+                nums.update(self.training_readings("control"))
+            elif name == "control_fold":
+                nums["fold_err"] = self.fold_number(control=True)
+            elif name == "control_codec":
+                part = self.codec_number(control=True)
+                if not part:
+                    continue
+                nums.update(part)
+            elif name == "half_batch":
+                nums.update(self.training_readings("half_batch"))
+            else:
+                raise KeyError(f"no variant named {name!r}")
+            out[name] = nums
+        return out

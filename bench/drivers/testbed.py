@@ -1,0 +1,215 @@
+"""Driver for the paper testbed through ``FedFlyScheduler``.
+
+The configuration file gives the model, optimizer and deployment; the
+traffic file gives the data partition, who moves when, and the
+migration codec. One ``Cell`` is built per run:
+
+``setup``   makes the data from the seed, builds the scheduler, and
+            trains round 0 as warm-up. Round 0 compiles every program
+            the window uses (the split step, the fold, the codec) and
+            feeds the training check: the first three steps of every
+            client go through the scheduler's own step and feed.
+``window``  runs whole rounds (``run_round``) until ``seconds`` have
+            passed; each round ends in a host read of the folded model.
+``numbers`` after the window: the training check, one fold of the
+            window against a numpy fold, and a sample of the window's
+            migrations against the codec's bound, with the packed
+            quantize's codes and scales against the numpy reference.
+"""
+from __future__ import annotations
+
+import math
+import time
+from typing import Any, Dict, List, Optional
+
+import data
+from cellbase import CellBase
+
+MAX_ROUNDS = 4096          # mobility trace length; a window uses far fewer
+
+
+def _tree_leaves(tree) -> List[Any]:
+    import jax
+    return jax.tree.leaves(tree)
+
+
+class Cell(CellBase):
+    def __init__(self, *args, **kw):
+        super().__init__(*args, **kw)
+        self.stalls: List[float] = []
+        self.fold_round = 1          # the window's first fold
+        self._client = None
+        self._move_t0: Optional[float] = None
+        self._quant = None
+        self._unpatch = lambda: None
+        self.round = 0
+
+    # -- build ------------------------------------------------------------
+
+    def setup(self) -> None:
+        from repro.core.mobility import MobilityTrace, MoveEvent
+        from repro.core.scheduler import FedFlyScheduler
+        from repro.data.datasets import ImageDataset
+        from repro.data.loader import Batcher
+        from repro.models.vgg import VGG5
+        from repro.optim.optimizers import sgd
+        from repro.optim.schedules import constant
+        from repro.runtime.cluster import (WIFI_75MBPS, make_testbed_devices,
+                                           make_testbed_edges)
+        cfg, tr = self.config, self.traffic
+        self.check_layers()
+        x, y = data.images(tr["samples"], cfg["image"], self.seed)
+        parts = data.split(tr["samples"], tr["fractions"], self.seed)
+        batchers = [Batcher(ImageDataset(x[i], y[i]), cfg["batch_size"],
+                            seed=self.seed) for i in parts]
+        del x, y
+        devices = make_testbed_devices(batchers, tuple(cfg["edges"]))
+        if [d.client_id for d in devices] != cfg["clients"]:
+            raise ValueError("configuration clients differ from the testbed")
+        sched = FedFlyScheduler(
+            VGG5(), sgd(momentum=cfg["momentum"]), devices,
+            make_testbed_edges(), split_point=cfg["split_point"],
+            lr_schedule=constant(cfg["lr"]), link=WIFI_75MBPS,
+            migration_codec=tr["codec"], seed=self.seed)
+        sched.initialize()
+        self.sched = sched
+        home = {d.client_id: d.edge_id for d in devices}
+        moves = tr.get("moves")
+        events = [] if not moves else data.handoffs(
+            cfg["clients"], cfg["edges"], home,
+            cfg["clients"] if moves["clients"] == "all" else moves["clients"],
+            moves["fraction"], MAX_ROUNDS, self.seed)
+        self.trace = MobilityTrace([MoveEvent(*e) for e in events])
+        self.batches_per_round = sum(d.batcher.num_batches
+                                     for d in devices)
+        self._instrument()
+        self.run_rounds(1)                       # warm-up: round 0
+        self.capture.on = False
+        if not self.capture.complete():
+            raise RuntimeError("warm-up did not capture three steps of "
+                               "every client")
+
+    def _instrument(self) -> None:
+        sched, spans = self.sched, self.spans
+        for dev in sched.devices.values():
+            batch_at = dev.batcher.batch_at
+
+            def wrapped(epoch, b, _f=batch_at, _c=dev.client_id):
+                self._client = _c
+                with spans.span("batch"):
+                    return _f(epoch, b)
+            dev.batcher.batch_at = wrapped
+
+        step = sched._step
+        bs = self.config["batch_size"]
+
+        def step_w(*args):
+            self.tick(bs)
+            with spans.span("step"):
+                outs = step(*args)
+            self.capture.record(self._client, args, outs)
+            return outs
+        sched._step = step_w
+
+        do_move = sched._do_move
+
+        def move_w(*args, **kw):
+            self._move_t0 = time.perf_counter()
+            self.capture.close(self._client)
+            with spans.span("migrate"):
+                return do_move(*args, **kw)
+        sched._do_move = move_w
+
+        costs = sched.cost_model.costs
+
+        def costs_w(*args, **kw):
+            # called right after the scheduler read the batch's loss back:
+            # the first one after a move ends that move's stall
+            if self._move_t0 is not None:
+                self.stalls.append(time.perf_counter() - self._move_t0)
+                self._move_t0 = None
+            return costs(*args, **kw)
+        sched.cost_model.costs = costs_w
+
+        migrate = sched.migrator.migrate
+
+        def migrate_w(ckpt, src, dst, **kw):
+            base = (sched.base_registry.base_for(dst)[0]
+                    if sched.base_registry is not None else None)
+            self._quant = None
+            restored, report = migrate(ckpt, src, dst, **kw)
+            if self.round > 0:           # the window's, not warm-up's
+                self.sample_migration(ckpt, base, restored, self._quant)
+            return restored, report
+        sched.migrator.migrate = migrate_w
+
+        # the packed quantize's inputs and outputs, as the codec ran it
+        from repro.kernels.int8_codec import ops as codec_ops
+        quantize = codec_ops.quantize_leaves
+
+        def quantize_w(leaves, base_leaves=None, **kw):
+            out = quantize(leaves, base_leaves, **kw)
+            self._quant = (list(leaves), None if base_leaves is None
+                           else list(base_leaves), out[0], out[1])
+            return out
+        codec_ops.quantize_leaves = quantize_w
+
+        def unpatch():
+            codec_ops.quantize_leaves = quantize
+        self._unpatch = unpatch
+
+        aggregate = sched._aggregate
+
+        def aggregate_w():
+            take = self.round == self.fold_round
+            if take:
+                trees, weights = [], []
+                for dev in sched.devices.values():
+                    st = sched.edges[dev.edge_id].clients[dev.client_id]
+                    trees.append(_tree_leaves(list(dev.dev_params)
+                                              + list(st.srv_params)))
+                    weights.append(dev.num_samples)
+            with spans.span("aggregate"):
+                aggregate()
+            if take:
+                self.fold = {"trees": trees, "weights": weights,
+                             "out": _tree_leaves(sched.global_params)}
+        sched._aggregate = aggregate_w
+
+    # -- run --------------------------------------------------------------
+
+    def run_rounds(self, n: int) -> Dict[str, int]:
+        import jax
+        out = {"batches": 0, "migrations": 0, "failed": 0}
+        nb = {c: d.batcher.num_batches for c, d in self.sched.devices.items()}
+        for _ in range(n):
+            rec = self.sched.run_round(self.round, self.trace)
+            jax.block_until_ready(self.sched.global_params)
+            self.round += 1
+            out["batches"] += self.batches_per_round
+            out["migrations"] += len(rec.migrations)
+            out["failed"] += sum(nb[c] for c, v in rec.client_losses.items()
+                                 if not math.isfinite(v))
+            out["failed"] += sum(1 for m in rec.migrations
+                                 if not math.isfinite(m.quant_error))
+        return out
+
+    def window(self, seconds: float) -> Dict[str, Any]:
+        self.stalls = []
+        totals = {"batches": 0, "migrations": 0, "failed": 0}
+        t0 = time.perf_counter()
+        while True:
+            for k, v in self.run_rounds(1).items():
+                totals[k] += v
+            elapsed = time.perf_counter() - t0
+            if elapsed >= seconds and self.fold is not None:
+                break
+        totals.update(elapsed_s=elapsed,
+                      samples=totals["batches"] * self.config["batch_size"],
+                      stalls_s=list(self.stalls))
+        return totals
+
+    def release(self) -> None:
+        """Drop the program's state before the reference runs."""
+        self._unpatch()
+        self.sched = None
