@@ -29,8 +29,8 @@ _MSG_TYPE = re.compile(r'\{"type":\s*"(\w+)"')
 _NAME_TOKEN = re.compile(r"`([^`]+)`")
 
 #: obs call attribute -> kind word used in the doc table
-_OBS_KINDS = {"span": "span", "count": "counter", "gauge": "gauge",
-              "observe": "hist"}
+_OBS_KINDS = {"span": "span", "record": "span", "count": "counter",
+              "gauge": "gauge", "observe": "hist"}
 
 
 # ---------------------------------------------------------------------------
@@ -174,25 +174,35 @@ def _code_message_types(project: Project) -> Dict[str, Tuple[str, int]]:
 
 def _code_obs_names(project: Project) -> Dict[str, Tuple[str, str, int]]:
     """``{name: (kind, path, line)}`` from obs.* calls with constant
-    names. Only receivers named ``obs``/``telemetry`` count."""
+    names. Only receivers named ``obs``/``telemetry`` count, and inside
+    the plane itself (``src/repro/obs/``) the bare calls with which it
+    records on its own (the GC hook's ``record``)."""
     out: Dict[str, Tuple[str, str, int]] = {}
     for pf in project.files_under(project.config["obs_scope"]):
-        if pf.tree is None or pf.path.startswith("src/repro/obs/"):
-            continue                     # the plane itself, not users
+        if pf.tree is None:
+            continue
+        plane = pf.path.startswith("src/repro/obs/")
         for node in ast.walk(pf.tree):
-            if not (isinstance(node, ast.Call)
-                    and isinstance(node.func, ast.Attribute)
-                    and node.func.attr in _OBS_KINDS):
+            if not isinstance(node, ast.Call):
                 continue
-            recv = dotted_name(node.func.value)
-            if recv is None \
-                    or recv.split(".")[-1] not in ("obs", "telemetry"):
-                continue
+            if plane:
+                if not (isinstance(node.func, ast.Name)
+                        and node.func.id in _OBS_KINDS):
+                    continue
+                kind = _OBS_KINDS[node.func.id]
+            else:
+                if not (isinstance(node.func, ast.Attribute)
+                        and node.func.attr in _OBS_KINDS):
+                    continue
+                recv = dotted_name(node.func.value)
+                if recv is None \
+                        or recv.split(".")[-1] not in ("obs", "telemetry"):
+                    continue
+                kind = _OBS_KINDS[node.func.attr]
             if node.args and isinstance(node.args[0], ast.Constant) \
                     and isinstance(node.args[0].value, str):
-                out.setdefault(
-                    node.args[0].value,
-                    (_OBS_KINDS[node.func.attr], pf.path, node.lineno))
+                out.setdefault(node.args[0].value,
+                               (kind, pf.path, node.lineno))
     return out
 
 

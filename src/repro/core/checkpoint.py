@@ -20,6 +20,7 @@ from typing import Any, Dict, Optional
 import jax
 import numpy as np
 
+from repro.obs import telemetry as obs
 from repro.runtime import serialization
 
 Params = Any
@@ -55,13 +56,18 @@ class EdgeCheckpoint:
             "loss": np.float64(self.loss),
             "rng_seed": np.int64(self.rng_seed),
         }
-        tree: Dict[str, Any] = {
-            "scalars": scalars,
-            "server_params": jax.tree.map(np.asarray, self.server_params),
-            "optimizer_state": jax.tree.map(np.asarray, self.optimizer_state),
-        }
-        if self.last_grads is not None:
-            tree["last_grads"] = jax.tree.map(np.asarray, self.last_grads)
+        arrays = (self.server_params, self.optimizer_state, self.last_grads)
+        on_device = sum(x.nbytes for x in jax.tree.leaves(arrays)
+                        if isinstance(x, jax.Array))
+        with obs.span("mig.fetch", bytes=on_device):   # device to host
+            tree: Dict[str, Any] = {
+                "scalars": scalars,
+                "server_params": jax.tree.map(np.asarray, self.server_params),
+                "optimizer_state": jax.tree.map(np.asarray,
+                                                self.optimizer_state),
+            }
+            if self.last_grads is not None:
+                tree["last_grads"] = jax.tree.map(np.asarray, self.last_grads)
         return tree
 
     @classmethod

@@ -19,6 +19,11 @@ The scheduler keeps two clocks per round and per client:
 All devices train logically in parallel; the round time is the max over
 clients (synchronous FL). Training is *deterministic* given seeds, so
 FedFly-vs-SplitFed comparisons are exact.
+
+With telemetry on (``repro.obs``), every batch records ``sched.put``,
+``sched.dispatch``, ``sched.readback`` and ``sched.cost`` spans and
+every move ``sched.move`` with ``sched.restore`` inside
+(docs/OBSERVABILITY.md).
 """
 from __future__ import annotations
 
@@ -35,6 +40,7 @@ from repro.core import split as split_lib
 from repro.core.checkpoint import EdgeCheckpoint
 from repro.core.migration import MigrationExecutor, MigrationReport
 from repro.core.mobility import MobilityTrace
+from repro.obs import telemetry as obs
 from repro.optim.optimizers import Optimizer
 from repro.runtime.checkpoint_manager import BaseVersionRegistry
 from repro.runtime.cluster import (Device, EdgeServer, ClientServerState,
@@ -178,8 +184,9 @@ class FedFlyScheduler:
 
         while b < nb:
             if move is not None and not moved and b == move_at:
-                t_sim += self._do_move(round_idx, dev, move, mode, record,
-                                       b, loss_val)
+                with obs.span("sched.move", client=client_id):
+                    t_sim += self._do_move(round_idx, dev, move, mode,
+                                           record, b, loss_val)
                 moved = True
                 edge = self.edges[dev.edge_id]
                 state = edge.clients[client_id]
@@ -187,21 +194,27 @@ class FedFlyScheduler:
                     b = 0           # restart the local epoch at destination
                 continue
 
-            batch = {k: jnp.asarray(v) for k, v in
-                     batcher.batch_at(state.epoch, b).items()}
-            batch = self._augment_batch(batch)
-            (dev.dev_params, state.srv_params, dev.dev_opt, state.srv_opt,
-             loss, g_srv) = self._step(dev.dev_params, state.srv_params,
-                                       dev.dev_opt, state.srv_opt, batch, lr)
-            loss_val = float(loss)
+            host = batcher.batch_at(state.epoch, b)
+            with obs.span("sched.put"):
+                batch = self._augment_batch(
+                    {k: jnp.asarray(v) for k, v in host.items()})
+            with obs.span("sched.dispatch"):
+                (dev.dev_params, state.srv_params, dev.dev_opt,
+                 state.srv_opt, loss, g_srv) = self._step(
+                    dev.dev_params, state.srv_params, dev.dev_opt,
+                    state.srv_opt, batch, lr)
+            with obs.span("sched.readback"):
+                loss_val = float(loss)
             state.last_loss = loss_val
             state.last_grads = g_srv
             state.batch_idx = b + 1
 
-            dflops, sflops, sbytes = self.cost_model.costs(
-                self.model, dev.dev_params, state.srv_params, batch, self.sp)
-            t_sim += batch_time_s(dev.profile, edge.profile, self.link,
-                                  dflops, sflops, sbytes)
+            with obs.span("sched.cost"):
+                dflops, sflops, sbytes = self.cost_model.costs(
+                    self.model, dev.dev_params, state.srv_params, batch,
+                    self.sp)
+                t_sim += batch_time_s(dev.profile, edge.profile, self.link,
+                                      dflops, sflops, sbytes)
             b += 1
 
         state.epoch += 1
@@ -246,9 +259,12 @@ class FedFlyScheduler:
                 ckpt, move.src_edge, move.dst_edge,
                 route=self.migration_route)
             record.migrations.append(report)
+            with obs.span("sched.restore"):
+                srv_params = jax.tree.map(jnp.asarray,
+                                          restored.server_params)
+                srv_opt = jax.tree.map(jnp.asarray, restored.optimizer_state)
             dst.clients[dev.client_id] = ClientServerState(
-                srv_params=jax.tree.map(jnp.asarray, restored.server_params),
-                srv_opt=jax.tree.map(jnp.asarray, restored.optimizer_state),
+                srv_params=srv_params, srv_opt=srv_opt,
                 epoch=restored.epoch, batch_idx=restored.batch_idx,
                 last_loss=restored.loss)
             return report.sim_total_s

@@ -2,7 +2,8 @@
 Chrome traces (``trace``), and rank-tagged logging (``log``). See
 docs/OBSERVABILITY.md."""
 from repro.obs.telemetry import (COORDINATOR_RANK, count, disable, enable,
-                                 gauge, is_enabled, observe, snapshot, span)
+                                 gauge, is_enabled, observe, record,
+                                 snapshot, span)
 
 __all__ = ["COORDINATOR_RANK", "count", "disable", "enable", "gauge",
-           "is_enabled", "observe", "snapshot", "span"]
+           "is_enabled", "observe", "record", "snapshot", "span"]

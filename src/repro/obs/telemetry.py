@@ -18,6 +18,14 @@ Collection model:
 * **Counters / gauges / histograms** live in one process-local
   registry behind a small lock; they are updated at frame/window
   granularity, never per simulated event.
+* ``record(name, t0_ns, dur_ns, **attrs)`` appends a span that has
+  already finished, timed elsewhere; ``from_wall`` puts a
+  ``time.time()`` reading on the span clock.
+* **Compiles**: ``enable()`` registers, once per process and only where
+  JAX is already imported (the plane never imports it), a
+  ``jax.monitoring`` listener that records every XLA backend compile,
+  or the persistent-cache load standing in for it, as a ``jit.compile``
+  span (attr ``fun``).
 * ``snapshot(reset=True)`` drains everything into a plain, wire-
   encodable tree (string-keyed dicts, numpy columns, scalar leaves) —
   the exact payload the ``stats`` record-plane message carries (see
@@ -29,6 +37,7 @@ Collection model:
 from __future__ import annotations
 
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -36,7 +45,11 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
-RING_CAP = 65536          # span events buffered per thread between drains
+# span events buffered per thread between drains: a 51 s benchmark
+# window of the testbed (about 13k split steps, four spans each, plus
+# the moves and compiles) is drained once, at its end, with room for
+# four times that rate
+RING_CAP = 1 << 18
 HIST_SAMPLE_CAP = 4096    # raw values kept per histogram (for percentiles)
 
 COORDINATOR_RANK = -1     # the convention every merge/trace consumer uses
@@ -105,6 +118,7 @@ def enable(rank: int = COORDINATOR_RANK,
     _state.process_name = process_name or (
         "coordinator" if rank == COORDINATOR_RANK else f"rank {rank}")
     _state.enabled = True
+    _watch_compiles()
 
 
 def disable() -> None:
@@ -129,11 +143,8 @@ class _Span:
         return self
 
     def __exit__(self, *exc) -> bool:
-        ring = _ring()
-        if len(ring.events) >= RING_CAP:
-            ring.dropped += 1     # deque evicts the oldest on append
-        ring.events.append(
-            (self.name, self.t0, time.monotonic_ns() - self.t0, self.attrs))
+        _append(_ring(), (self.name, self.t0, time.monotonic_ns() - self.t0,
+                          self.attrs))
         return False
 
 
@@ -157,6 +168,48 @@ def span(name: str, **attrs):
     if not _state.enabled:
         return _NOOP
     return _Span(name, attrs or None)
+
+
+def record(name: str, t0_ns: int, dur_ns: int, **attrs) -> None:
+    """Append a span that has already ended: ``t0_ns`` on the span clock
+    (``time.monotonic_ns``; ``from_wall`` converts), ``dur_ns`` long."""
+    if not _state.enabled:
+        return
+    _append(_ring(), (name, int(t0_ns), int(dur_ns), attrs or None))
+
+
+def from_wall(wall_s: float) -> int:
+    """A ``time.time()`` reading, in seconds, on the span clock."""
+    return time.monotonic_ns() - (time.time_ns() - int(wall_s * 1e9))
+
+
+def _append(ring: "_Ring", event: tuple) -> None:
+    if len(ring.events) >= RING_CAP:
+        ring.dropped += 1         # deque evicts the oldest on append
+    ring.events.append(event)
+
+
+# JAX reports each backend compile (a persistent-cache load included)
+# as a time span whose bounds are time.time() readings
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_compiles_watched = False
+
+
+def _watch_compiles() -> None:
+    global _compiles_watched
+    if "jax" not in sys.modules:          # the plane never imports JAX
+        return
+    with _state.lock:
+        if not _compiles_watched:
+            import jax.monitoring
+            jax.monitoring.register_event_time_span_listener(_on_time_span)
+            _compiles_watched = True
+
+
+def _on_time_span(event: str, start: float, end: float, **kw) -> None:
+    if event == COMPILE_EVENT:
+        record("jit.compile", from_wall(start), int((end - start) * 1e9),
+               fun=str(kw.get("fun_name", "")))
 
 
 def _ring() -> _Ring:

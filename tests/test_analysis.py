@@ -498,6 +498,22 @@ def test_drift_catches_obs_name_drift(tmp_path):
             lint(tmp_path, DRIFT_CFG, rules=[WireSpecDrift()])]
     assert any('"w.depth"' in m for m in msgs)
 
+def test_drift_sees_recorded_spans_and_the_planes_own(tmp_path):
+    # a span recorded after the fact, and one the telemetry plane
+    # records by itself (a bare call inside src/repro/obs/), both
+    # undocumented
+    root = _drift_tree(tmp_path, tag_rows=GOOD_TAGS, version_line=GOOD_VER)
+    user = root / "src/pkg/user.py"
+    user.write_text(user.read_text()
+                    + '\ndef g():\n    obs.record("m.compile", 0, 1)\n')
+    plane = root / "src/repro/obs/telemetry.py"
+    plane.parent.mkdir(parents=True)
+    plane.write_text('def hook():\n    record("h.gc", 0, 1)\n')
+    msgs = [f.message for f in
+            lint(tmp_path, DRIFT_CFG, rules=[WireSpecDrift()])]
+    assert any('"m.compile" (span)' in m for m in msgs)
+    assert any('"h.gc" (span)' in m for m in msgs)
+
 def test_obs_table_suffix_expansion():
     names = parse_obs_table(
         "## What is instrumented\n\n"
