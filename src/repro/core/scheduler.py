@@ -20,10 +20,16 @@ All devices train logically in parallel; the round time is the max over
 clients (synchronous FL). Training is *deterministic* given seeds, so
 FedFly-vs-SplitFed comparisons are exact.
 
+A batch's loss stays on the device until the protocol needs it as a
+float: before a move (the checkpoint carries it), on the first batch
+trained after a move (its arrival ends the migration stall), and at
+the epoch's end (the round's record). Between those points the host
+prepares and dispatches the next batch while the device runs the last.
+
 With telemetry on (``repro.obs``), every batch records ``sched.put``,
-``sched.dispatch``, ``sched.readback`` and ``sched.cost`` spans and
-every move ``sched.move`` with ``sched.restore`` inside
-(docs/OBSERVABILITY.md).
+``sched.dispatch`` and ``sched.cost`` spans, each of the three reads a
+``sched.readback`` span, and every move ``sched.move`` with
+``sched.restore`` inside (docs/OBSERVABILITY.md).
 """
 from __future__ import annotations
 
@@ -179,6 +185,7 @@ class FedFlyScheduler:
         t_sim = 0.0
         t_wall0 = time.perf_counter()
         moved = False
+        resumed = False     # the next batch is the first after the move
         b = state.batch_idx
         loss_val = state.last_loss
 
@@ -187,7 +194,7 @@ class FedFlyScheduler:
                 with obs.span("sched.move", client=client_id):
                     t_sim += self._do_move(round_idx, dev, move, mode,
                                            record, b, loss_val)
-                moved = True
+                moved = resumed = True
                 edge = self.edges[dev.edge_id]
                 state = edge.clients[client_id]
                 if mode == "splitfed":
@@ -203,11 +210,17 @@ class FedFlyScheduler:
                  state.srv_opt, loss, g_srv) = self._step(
                     dev.dev_params, state.srv_params, dev.dev_opt,
                     state.srv_opt, batch, lr)
-            with obs.span("sched.readback"):
-                loss_val = float(loss)
-            state.last_loss = loss_val
             state.last_grads = g_srv
             state.batch_idx = b + 1
+            # the protocol's sync points: the move next checkpoints this
+            # loss; the first batch after a move ends the stall only once
+            # its loss is on the host; the last batch ends the epoch
+            if (resumed or b == nb - 1
+                    or (not moved and b + 1 == move_at)):
+                with obs.span("sched.readback"):
+                    loss_val = float(loss)
+                state.last_loss = loss_val
+                resumed = False
 
             with obs.span("sched.cost"):
                 dflops, sflops, sbytes = self.cost_model.costs(

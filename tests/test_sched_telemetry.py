@@ -1,5 +1,6 @@
 """The scheduler's own telemetry: one span of each kind per training
-step and per move, nested where the docs say; no effect on the numbers;
+step (the loss's read-back only at the protocol's sync points) and per
+move, nested where the docs say; no effect on the numbers;
 compiles recorded as spans on the span clock; a benchmark window's
 worth of events kept without drops."""
 from __future__ import annotations
@@ -65,9 +66,12 @@ def test_scheduler_spans_per_step_round_and_move(batchers):
     counts = Counter(n for n, *_ in spans)
     steps = sum(b.num_batches for b in batchers)
     assert steps == 2 * PER_CLIENT // BATCH
-    for name in ("sched.put", "sched.dispatch", "sched.readback",
-                 "sched.cost"):
+    for name in ("sched.put", "sched.dispatch", "sched.cost"):
         assert counts[name] == steps, name
+    # the loss is read back at the protocol's sync points only: pi3_1
+    # before its move, on the first batch after it and at the epoch's
+    # end; pi3_2 at the epoch's end
+    assert counts["sched.readback"] == 4
     assert len(rec.migrations) == 1
     for name in ("sched.move", "mig.pack", "mig.fetch", "mig.unpack",
                  "sched.restore"):
