@@ -1,11 +1,21 @@
 """What every driver's cell shares: what it keeps from the window for the
 comparison, and the numbers it turns that into.
 
-A driver fills in, while its system runs:
+A driver's ``Cell`` subclasses ``CellBase`` and gives the parts that
+depend on its model:
+
+``merge(dev, srv)``       the full parameter tree from a device stage and
+                          a server stage, leaves in the order of the
+                          reference's (``bench/refs/<reference>.py``); it
+                          serves the training check and the fold sample.
+``first_grad(opt_state)`` the gradient an optimizer state holds after one
+                          step from its init, as the optimizer got it.
+
+and fills in, while its system runs:
 
 ``capture``  the first three steps of each client (``steps.StepCapture``).
 ``fold``     one fold of the window: ``{"trees", "weights", "out"}``, a
-             weighted mean (FedAvg).
+             weighted mean (FedAvg), each tree a list of leaves.
 ``migs``     a reservoir sample of the window's migrations
              (``sample_migration``), each with the packed quantize's
              inputs and outputs where the codec ran it.
@@ -66,15 +76,13 @@ class CellBase:
         and its (dev, srv, dev_opt, srv_opt, loss, ...)."""
         return {"params": (args[0], args[1]), "batch": args[4],
                 "new_params": (outs[0], outs[1]),
-                "new_mu": (outs[2]["mu"], outs[3]["mu"]), "loss": outs[4]}
+                "new_opt": (outs[2], outs[3]), "loss": outs[4]}
 
-    def check_layers(self) -> None:
-        from repro.models.vgg import VGG5_LAYERS
-        want = [tuple(l) for l in self.config["layers"]]
-        have = [(k, *s) for k, s in VGG5_LAYERS]
-        if want != have:
-            raise ValueError(f"configuration layers {want} are not the "
-                             f"program's VGG-5 {have}")
+    def merge(self, dev, srv):
+        raise NotImplementedError("a driver's Cell gives merge(dev, srv)")
+
+    def first_grad(self, opt_state):
+        raise NotImplementedError("a driver's Cell gives first_grad(state)")
 
     def sample_migration(self, ckpt, base, restored, quant=None) -> None:
         """``quant``: (leaves, bases, codes, scales) of the packed
@@ -105,7 +113,8 @@ class CellBase:
             ref = reference_readings(self.ref, self.config, self.seed,
                                      streams, precision)
             if mode == "program":
-                got = program_readings(streams)
+                got = program_readings(streams, self.merge,
+                                       self.first_grad)
             else:
                 got = reference_readings(
                     self.ref, self.config, self.seed, streams, precision,

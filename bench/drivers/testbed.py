@@ -15,6 +15,23 @@ migration codec. One ``Cell`` is built per run:
             window against a numpy fold, and a sample of the window's
             migrations against the codec's bound, with the packed
             quantize's codes and scales against the numpy reference.
+
+What depends on the model are hooks, VGG-5's by default:
+
+``check_config()``   raises where the configuration is not the model the
+                     program builds.
+``build_model()``    the program's model.
+``build_optimizer()`` the program's optimizer.
+``build_batchers()`` one batcher per client, from the seed and the
+                     traffic's shares: ``batch_at(epoch, b)`` gives a
+                     batch (a dict of arrays), ``num_batches`` their
+                     count an epoch, ``len(ds)`` the client's samples.
+``merge(dev, srv)``, ``first_grad(opt_state)``  as ``cellbase`` says.
+
+Another configuration's driver is a file of its own that loads this one
+(``harness.driver("testbed")``), subclasses its ``Cell`` and overrides
+the hooks that differ; the scheduler, the instrumentation and the window
+stay here.
 """
 from __future__ import annotations
 
@@ -46,28 +63,52 @@ class Cell(CellBase):
 
     # -- build ------------------------------------------------------------
 
+    def check_config(self) -> None:
+        from repro.models.vgg import VGG5_LAYERS
+        want = [tuple(l) for l in self.config["layers"]]
+        have = [(k, *s) for k, s in VGG5_LAYERS]
+        if want != have:
+            raise ValueError(f"configuration layers {want} are not the "
+                             f"program's VGG-5 {have}")
+
+    def build_model(self):
+        from repro.models.vgg import VGG5
+        return VGG5()
+
+    def build_optimizer(self):
+        from repro.optim.optimizers import sgd
+        return sgd(momentum=self.config["momentum"])
+
+    def build_batchers(self) -> List[Any]:
+        from repro.data.datasets import ImageDataset
+        from repro.data.loader import Batcher
+        cfg, tr = self.config, self.traffic
+        x, y = data.images(tr["samples"], cfg["image"], self.seed)
+        parts = data.split(tr["samples"], tr["fractions"], self.seed)
+        return [Batcher(ImageDataset(x[i], y[i]), cfg["batch_size"],
+                        seed=self.seed) for i in parts]
+
+    def merge(self, dev, srv):
+        return list(dev) + list(srv)
+
+    def first_grad(self, opt_state):
+        return opt_state["mu"]
+
     def setup(self) -> None:
         from repro.core.mobility import MobilityTrace, MoveEvent
         from repro.core.scheduler import FedFlyScheduler
-        from repro.data.datasets import ImageDataset
-        from repro.data.loader import Batcher
-        from repro.models.vgg import VGG5
-        from repro.optim.optimizers import sgd
         from repro.optim.schedules import constant
         from repro.runtime.cluster import (WIFI_75MBPS, make_testbed_devices,
                                            make_testbed_edges)
         cfg, tr = self.config, self.traffic
-        self.check_layers()
-        x, y = data.images(tr["samples"], cfg["image"], self.seed)
-        parts = data.split(tr["samples"], tr["fractions"], self.seed)
-        batchers = [Batcher(ImageDataset(x[i], y[i]), cfg["batch_size"],
-                            seed=self.seed) for i in parts]
-        del x, y
-        devices = make_testbed_devices(batchers, tuple(cfg["edges"]))
+        self.check_config()
+        devices = make_testbed_devices(self.build_batchers(),
+                                       tuple(cfg["edges"]))
         if [d.client_id for d in devices] != cfg["clients"]:
             raise ValueError("configuration clients differ from the testbed")
+        self.model = self.build_model()
         sched = FedFlyScheduler(
-            VGG5(), sgd(momentum=cfg["momentum"]), devices,
+            self.model, self.build_optimizer(), devices,
             make_testbed_edges(), split_point=cfg["split_point"],
             lr_schedule=constant(cfg["lr"]), link=WIFI_75MBPS,
             migration_codec=tr["codec"], seed=self.seed)
@@ -166,8 +207,8 @@ class Cell(CellBase):
                 trees, weights = [], []
                 for dev in sched.devices.values():
                     st = sched.edges[dev.edge_id].clients[dev.client_id]
-                    trees.append(_tree_leaves(list(dev.dev_params)
-                                              + list(st.srv_params)))
+                    trees.append(_tree_leaves(self.merge(dev.dev_params,
+                                                         st.srv_params)))
                     weights.append(dev.num_samples)
             with spans.span("aggregate"):
                 aggregate()

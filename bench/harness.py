@@ -7,6 +7,15 @@ topology: the entry point decides when JAX starts.
   name (``configs/<config>.json``, ``traffic/<mix>.json``,
   ``limits/<workload>.json``, ``metrics/<metric>.py``,
   ``drivers/<driver>.py``, ``refs/<reference>.py``).
+  What depends on the model lives in the configuration's own files, so a
+  new configuration adds files and edits none: its file names a
+  ``driver``, whose ``Cell`` builds and runs the system and gives
+  ``merge`` and ``first_grad`` (``cellbase``; the testbed driver's hooks
+  can be overridden by a subclass in a file of its own), and a
+  ``reference``, whose ``init(seed, config)``, ``train_steps(params,
+  batches, config, dtype, precision)`` and
+  ``train_flops_per_sample(config)`` give the plain reference and the
+  operations a sample of training needs (``refs/vgg5.py``).
 - ``Spans``: the harness's own host spans around calls into the program.
 - ``CompileCounter``: backend compiles, counted by a ``jax.monitoring``
   listener.

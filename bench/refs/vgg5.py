@@ -12,19 +12,46 @@ the recipe the configuration states (He-normal, zero bias, one
 ``dtype=jnp.bfloat16`` runs the same mathematics with parameters,
 activations, gradients and optimizer state held in bfloat16: the
 control, one precision step below what the configuration states.
+
+What every reference gives the harness, each taking the configuration
+as its file states it:
+
+``init(seed, config)``        the weights, from the seed.
+``train_steps(params, batches, config, dtype, precision)``  the steps
+                              over ``batches``, the optimizer read from
+                              the configuration.
+``train_flops_per_sample(config)``  operations a sample of training
+                              needs, for ``split_step.mfu``.
+
+Here the model is the configuration's ``layers`` over ``image``-shaped
+inputs, the optimizer SGD with its ``lr`` and ``momentum``, and a batch
+holds ``images`` and ``labels``.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Sequence
+from typing import Any, Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
+
+import flops
 
 PRECISION = {"highest": jax.lax.Precision.HIGHEST,
              "default": jax.lax.Precision.DEFAULT}
 
 
-def init(seed: int, layers: Sequence[Sequence]) -> List[Dict[str, jax.Array]]:
+def _layers(config: Dict[str, Any]) -> Tuple[Tuple, ...]:
+    return tuple(tuple(l) for l in config["layers"])
+
+
+def train_flops_per_sample(config: Dict[str, Any]) -> int:
+    """Three forward passes of the configuration's shapes
+    (``bench/flops.py``)."""
+    return flops.train_per_sample(config["layers"], config["image"])
+
+
+def init(seed: int, config: Dict[str, Any]) -> List[Dict[str, jax.Array]]:
+    layers = _layers(config)
     keys = jax.random.split(jax.random.PRNGKey(seed), len(layers))
     params = []
     for k, layer in zip(keys, layers):
@@ -67,12 +94,14 @@ def loss(params, images, labels, layers, precision="highest"):
     return -jnp.mean(jnp.take_along_axis(lp, labels[:, None], axis=-1))
 
 
-def train_steps(params, batches, layers, lr: float, momentum: float,
+def train_steps(params, batches, config: Dict[str, Any],
                 dtype=jnp.float32, precision="highest"):
     """SGD with momentum (mu <- momentum*mu + g; p <- p - lr*mu) over
     ``batches``, from zero momentum. Returns the loss of each step, the
     first step's gradient and the parameters after the last step, all
     as float32."""
+    layers = _layers(config)
+    lr, momentum = float(config["lr"]), float(config["momentum"])
     cast = lambda t: jax.tree.map(lambda x: x.astype(dtype), t)
     p = cast(params)
     mu = jax.tree.map(jnp.zeros_like, p)

@@ -11,8 +11,9 @@ start to the first timed batch: data from the seed, the system, and a
 warm-up round that compiles every program the window uses. The window
 then runs whole rounds for ``--seconds``. After it, the program's state
 is freed and what the window produced is compared with the plain
-reference (``bench/refs``); every number compared is printed beside its
-limit (``bench/limits/<cell>.json``).
+reference (``bench/refs/<reference>.py``, named by the configuration,
+which also counts the training FLOPs a sample); every number compared is
+printed beside its limit (``bench/limits/<cell>.json``).
 
 ``--trace 0`` reports the cell's end-to-end metrics. ``--trace 1``
 profiles a slice of a few seconds inside the window, with the program's
@@ -44,7 +45,6 @@ sys.path.insert(0, str(BENCH))
 sys.path.insert(0, str(BENCH.parent / "src"))
 
 import check  # noqa: E402
-import flops  # noqa: E402
 import harness  # noqa: E402
 import trace_reduce  # noqa: E402
 
@@ -200,9 +200,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         telemetry.enable()
     try:
         drv = harness.driver(config["driver"], root)
+        ref = harness.reference(config["reference"], root)
         cell = drv.Cell(config, traffic, seed, spans, tick=prof.tick,
-                        reference=harness.reference(config["reference"],
-                                                    root))
+                        reference=ref)
         cell.setup()
         setup_s = time.perf_counter() - T_START
         spans.reset()
@@ -261,8 +261,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
                 "compiles_in_window": compiles,
                 "step_program": step_program,
                 "rest_samples_per_s": rates.get("rest_samples_per_s"),
-                "train_flops_per_sample": flops.train_per_sample(
-                    config["layers"], config["image"]),
+                "train_flops_per_sample": ref.train_flops_per_sample(
+                    config),
                 "peak": (harness.peaks(device["kind"], root)
                          if require_chip else None),
             }

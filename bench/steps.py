@@ -8,11 +8,17 @@ going in, the batch, and what came out. Nothing is copied to the host
 and nothing is synchronised, so the step runs as it does in the window.
 
 ``program_readings`` turns a capture into the numbers ``check``
-compares; ``reference_readings`` runs the plain reference (or its
-control, or a planted fault) over the same batches.
+compares, through two functions the cell supplies: ``merge(dev, srv)``,
+the full parameter tree in the reference's leaf order, and
+``first_grad(opt_state)``, the gradient an optimizer state holds after
+one step from its init. ``reference_readings`` runs the plain reference
+(or its control, or a planted fault) over the same batches, every key of
+them; the reference reads its model and optimizer from the
+configuration.
 """
 from __future__ import annotations
 
+import json
 from typing import Any, Callable, Dict, List
 
 import numpy as np
@@ -23,7 +29,7 @@ STEPS = 3
 class StepCapture:
     def __init__(self, unpack: Callable[[tuple, tuple], Dict[str, Any]]):
         """``unpack(args, outs)`` -> {"params": (dev, srv), "batch",
-        "new_params": (dev, srv), "new_mu": (dev, srv), "loss"}."""
+        "new_params": (dev, srv), "new_opt": (dev, srv), "loss"}."""
         self.unpack = unpack
         self.streams: Dict[Any, List[Dict[str, Any]]] = {}
         self.on = True
@@ -44,10 +50,9 @@ class StepCapture:
             len(c) == STEPS for c in self.streams.values())
 
 
-def _leaves(pair) -> List[np.ndarray]:
+def _leaves(tree) -> List[np.ndarray]:
     import jax
-    dev, srv = pair
-    return [np.asarray(x) for x in jax.tree.leaves(list(dev) + list(srv))]
+    return [np.asarray(x) for x in jax.tree.leaves(tree)]
 
 
 def _stack(per_stream: List[List[np.ndarray]]) -> List[np.ndarray]:
@@ -55,16 +60,21 @@ def _stack(per_stream: List[List[np.ndarray]]) -> List[np.ndarray]:
     return [np.stack(xs) for xs in zip(*per_stream)]
 
 
-def program_readings(streams: List[List[Dict[str, Any]]]) -> Dict[str, Any]:
+def program_readings(streams: List[List[Dict[str, Any]]], merge: Callable,
+                     first_grad: Callable) -> Dict[str, Any]:
     """(losses (S, R), g1 leaves (R, ...), delta leaves (R, ...)) over
-    every stream."""
-    p0 = _stack([_leaves(c[0]["params"]) for c in streams])
-    p3 = _stack([_leaves(c[-1]["new_params"]) for c in streams])
+    every stream; ``merge`` and ``first_grad`` as the module says."""
+    def full(pair):
+        return _leaves(merge(*pair))
+
+    p0 = _stack([full(c[0]["params"]) for c in streams])
+    p3 = _stack([full(c[-1]["new_params"]) for c in streams])
     losses = np.array([[float(c[k]["loss"]) for c in streams]
                        for k in range(STEPS)])
     return {
         "losses": losses,
-        "g1": _stack([_leaves(c[0]["new_mu"]) for c in streams]),
+        "g1": _stack([full(tuple(first_grad(o) for o in c[0]["new_opt"]))
+                      for c in streams]),
         "delta": [b.astype(np.float64) - a for a, b in zip(p0, p3)],
     }
 
@@ -72,16 +82,18 @@ def program_readings(streams: List[List[Dict[str, Any]]]) -> Dict[str, Any]:
 _REFERENCE = {}
 
 
-def _reference_fn(ref, layers, lr, momentum, dtype, precision):
-    """One jitted program per (reference, dtype, precision): the weights
-    and the batches are arguments, so every seed runs the same program
-    and a run after the first finds it in the compilation cache."""
+def _reference_fn(ref, config, dtype, precision):
+    """One jitted program per (reference, configuration, dtype,
+    precision): the weights and the batches are arguments, so every seed
+    runs the same program and a run after the first finds it in the
+    compilation cache."""
     import jax
-    key = (ref.__name__, layers, lr, momentum, str(dtype), precision)
+    key = (ref.__name__, json.dumps(config, sort_keys=True), str(dtype),
+           precision)
     if key not in _REFERENCE:
         def one(params, batches):
-            return ref.train_steps(params, batches, layers, lr, momentum,
-                                   dtype, precision)
+            return ref.train_steps(params, batches, config, dtype,
+                                   precision)
         _REFERENCE[key] = jax.jit(jax.vmap(one, in_axes=(None, 0)))
     return _REFERENCE[key]
 
@@ -97,20 +109,18 @@ def reference_readings(ref, config: Dict[str, Any], seed: int,
     (``half_batch``, a planted fault)."""
     import jax
     import jax.numpy as jnp
-    layers = tuple(tuple(l) for l in config["layers"])
     dtype = jnp.bfloat16 if control else jnp.float32
-    params = ref.init(seed, layers)
+    params = ref.init(seed, config)
     batches = []
     for k in range(STEPS):
         b = {}
-        for name in ("images", "labels"):
+        for name in streams[0][k]["batch"]:
             x = np.stack([np.asarray(c[k]["batch"][name]) for c in streams])
             if half_batch:
                 x = x[:, : x.shape[1] // 2]
             b[name] = jnp.asarray(x)
         batches.append(b)
-    fn = _reference_fn(ref, layers, float(config["lr"]),
-                       float(config["momentum"]), dtype, precision)
+    fn = _reference_fn(ref, config, dtype, precision)
     losses, g1, p3 = fn(params, batches)
     p0 = [np.asarray(x, np.float64)[None] for x in jax.tree.leaves(params)]
     return {

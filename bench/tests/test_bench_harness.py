@@ -1,6 +1,8 @@
-"""The benchmark's files are found by name, new cells need no edit to an
-existing file, operations are counted from shapes, and the command
-refuses a machine without an accelerator."""
+"""The benchmark's files are found by name, new cells and new
+configurations need no edit to an existing file, the parts that depend
+on the model come from the configuration's own files, operations are
+counted from shapes, and the command refuses a machine without an
+accelerator."""
 from __future__ import annotations
 
 import json
@@ -18,6 +20,14 @@ sys.path.insert(0, str(BENCH))
 
 import flops  # noqa: E402
 import harness  # noqa: E402
+
+TINY_LM = BENCH / "tests" / "data" / "tiny-lm"
+#: the tiny token model's files, and where a configuration puts them
+TINY_LM_FILES = {"config.json": "configs/tiny-lm.json",
+                 "driver.py": "drivers/tinylm.py",
+                 "reference.py": "refs/tinylm.py",
+                 "traffic.json": "traffic/tokens-handoff.json",
+                 "limits.json": "limits/tiny-lm-handoff.json"}
 
 
 def test_vgg5_forward_flops_from_shapes():
@@ -80,6 +90,129 @@ def test_a_new_cell_needs_no_edit_to_an_existing_file(tmp_path):
     assert read({"obs": {"mig.pack": [(0.001, {})] * 3}}) == 3
     after = {p: p.read_bytes() for p in before}
     assert after == before
+
+
+def _bench_files(root):
+    return {p: p.read_bytes() for p in (root / "bench").rglob("*")
+            if p.is_file() and "__pycache__" not in p.parts}
+
+
+@pytest.fixture(scope="module")
+def tiny_lm_checkout(tmp_path_factory):
+    """A copy of the benchmark with a token model added as new files
+    only, and the bytes of every file the copy had before."""
+    root = tmp_path_factory.mktemp("tiny-lm") / "checkout"
+    shutil.copytree(BENCH, root / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    before = _bench_files(root)
+    for src, dst in TINY_LM_FILES.items():
+        assert not (root / "bench" / dst).exists()
+        shutil.copy(TINY_LM / src, root / "bench" / dst)
+    spec = json.loads((ROOT / "BENCHMARK.json").read_text())
+    spec["configs"].append({
+        "name": "tiny-lm", "source": "https://arxiv.org/abs/2403.04652",
+        "file": "bench/configs/tiny-lm.json",
+        "reduced": ["num_layers", "d_model", "vocab_size"],
+        "why": "test-only: a token model through FedFlyScheduler"})
+    spec["workloads"].append({
+        "name": "tiny-lm-handoff", "config": "tiny-lm",
+        "traffic": "tokens-handoff", "chips": 1,
+        "why": "test-only: one handoff a round, raw codec"})
+    (root / "BENCHMARK.json").write_text(json.dumps(spec))
+    return root, before
+
+
+def _run_tiny_lm(root):
+    from _small import SECONDS, SEED
+    import run
+    return run.run_cell("tiny-lm-handoff", SEED, SECONDS, False,
+                        require_chip=False, root=root)
+
+
+def test_a_new_configuration_needs_no_edit_to_an_existing_file(
+        tiny_lm_checkout):
+    root, before = tiny_lm_checkout
+    r = _run_tiny_lm(root)
+    assert r["correct"], r["checks"]
+    assert r["failed"] == 0
+    assert r["window"]["migrations"] >= 1
+    assert r["window"]["stalls"] >= 1
+    assert set(r["checks"]) == {"loss_gap", "grad_gap", "update_gap",
+                                "grad_gap_f32", "update_gap_f32",
+                                "fold_err", "raw_mismatch"}
+    after = _bench_files(root)
+    assert {p: after[p] for p in before} == before
+
+
+def test_a_new_configurations_check_catches_half_a_batch(
+        tiny_lm_checkout, monkeypatch):
+    from _small import failed_checks, half_batch_step
+    from repro.core.scheduler import FedFlyScheduler
+    root, before = tiny_lm_checkout
+    build = FedFlyScheduler._build_step
+
+    def patched(self):
+        build(self)
+        self._step = half_batch_step(self._step)
+    monkeypatch.setattr(FedFlyScheduler, "_build_step", patched)
+    r = _run_tiny_lm(root)
+    assert not r["correct"]
+    assert {"loss_gap", "update_gap"} & set(failed_checks(r)), r["checks"]
+    after = _bench_files(root)
+    assert {p: after[p] for p in before} == before
+
+
+def test_the_reference_counts_vgg5_training_flops():
+    cfg = harness.load_json(BENCH / "configs" / "vgg5-testbed.json")
+    ref = harness.reference(cfg["reference"])
+    assert ref.train_flops_per_sample(cfg) == 48_569_856
+
+
+def test_reference_readings_stack_every_key_of_the_batch():
+    import types
+
+    import numpy as np
+    import steps
+    seen = []
+
+    def train_steps(params, batches, config, dtype, precision):
+        seen.append({k: v.shape for k, v in batches[0].items()})
+        loss = sum(b["frames"].sum() + b["ids"].sum() for b in batches)
+        return loss[None] + params["w"][:steps.STEPS], params, params
+
+    ref = types.SimpleNamespace(
+        __name__="keys_probe", train_steps=train_steps,
+        init=lambda seed, config: {"w": np.ones(4, np.float32)})
+    streams = [[{"batch": {"frames": np.full((6, 3), c, np.float32),
+                           "ids": np.arange(6, dtype=np.int32)}}
+                for _ in range(steps.STEPS)] for c in range(2)]
+    out = steps.reference_readings(ref, {}, 0, streams)
+    steps.reference_readings(ref, {}, 0, streams, half_batch=True)
+    assert seen == [{"frames": (6, 3), "ids": (6,)},
+                    {"frames": (3, 3), "ids": (3,)}]
+    # one row per stream: stream c's frames sum to 18c each step
+    np.testing.assert_allclose(out["losses"][:, 1] - out["losses"][:, 0],
+                               3 * 18)
+
+
+def test_vgg5_merge_gives_the_references_leaves():
+    import jax
+    import numpy as np
+    from repro.core import split
+    from repro.models.vgg import VGG5
+    cfg = harness.load_json(BENCH / "configs" / "vgg5-testbed.json")
+    tr = harness.load_json(BENCH / "traffic" / "paper.json")
+    cell = harness.driver("testbed").Cell(cfg, tr, 7, harness.Spans())
+    model = VGG5()
+    dev, srv = split.partition_params(
+        model, model.init(jax.random.PRNGKey(7)), cfg["split_point"])
+    merged = jax.tree.leaves(cell.merge(dev, srv))
+    assert all(a is b for a, b in zip(
+        merged, jax.tree.leaves(list(dev) + list(srv))))
+    ref = jax.tree.leaves(harness.reference("vgg5").init(7, cfg))
+    assert [x.shape for x in merged] == [x.shape for x in ref]
+    for a, b in zip(merged, ref):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_peaks_refuse_an_unknown_device():
