@@ -1,5 +1,7 @@
-"""What every driver's cell shares: what it keeps from the window for the
-comparison, and the numbers it turns that into.
+"""What every driver's cell shares: what it keeps for the comparison,
+and the numbers it turns that into. It keeps host data only, and only
+what it compares, so the check takes no device memory from the program
+and a step that donates its inputs leaves it whole.
 
 A driver's ``Cell`` subclasses ``CellBase`` and gives the parts that
 depend on its model:
@@ -7,18 +9,26 @@ depend on its model:
 ``merge(dev, srv)``       the full parameter tree from a device stage and
                           a server stage, leaves in the order of the
                           reference's (``bench/refs/<reference>.py``); it
-                          serves the training check and the fold sample.
+                          serves the training check and the fold sample,
+                          and runs on host copies (``host_leaves``).
 ``first_grad(opt_state)`` the gradient an optimizer state holds after one
                           step from its init, as the optimizer got it.
 
 and fills in, while its system runs:
 
-``capture``  the first three steps of each client (``steps.StepCapture``).
-``fold``     one fold of the window: ``{"trees", "weights", "out"}``, a
-             weighted mean (FedAvg), each tree a list of leaves.
+``capture``  the first three steps of each client, reduced to the
+             numbers the training check compares (``steps.StepCapture``).
+``fold``     one fold, the warm-up round's, reduced as it is taken
+             (``take_fold``) to the two numbers compared: ``{"program",
+             "control"}``, its ``fold_err`` and the control's.
 ``migs``     a reservoir sample of the window's migrations
-             (``sample_migration``), each with the packed quantize's
-             inputs and outputs where the codec ran it.
+             (``sample_migration``): the host tree the move's own fetch
+             made, the base, the restored state's host fields and, where
+             the codec ran the packed quantize, its inputs and outputs.
+             It keeps ``SAMPLED_MIGRATIONS`` where they fit in
+             ``MIGRATION_SAMPLE_BYTES`` of host memory, else as many as
+             fit, and at least one, so a single migration larger than
+             the budget is kept whole all the same.
 
 ``numbers`` compares what the program produced; ``variants`` puts the
 control (the reference one precision step down) or a planted fault in
@@ -33,9 +43,13 @@ from typing import Any, Callable, Dict, List, Optional
 import numpy as np
 
 import check
-from steps import StepCapture, program_readings, reference_readings
+from steps import StepCapture, reference_readings
 
 SAMPLED_MIGRATIONS = 8
+#: host bytes the migration sample may hold (a sample's trees, base,
+#: restored state and quantize codes, each array counted once); one
+#: sample is kept even where it alone is larger
+MIGRATION_SAMPLE_BYTES = 2 << 30
 CHECKPOINT_PARTS = ("server_params", "optimizer_state", "last_grads",
                     "scalars")
 #: the training comparison's two references: the configuration's stated
@@ -45,14 +59,16 @@ PRECISIONS = (("default", ""), ("highest", "_f32"))
 VARIANTS = ("control_step", "control_fold", "control_codec", "half_batch")
 
 
-def _leaves(tree) -> List[np.ndarray]:
-    import jax
-    return [np.asarray(x) for x in jax.tree.leaves(tree)]
-
-
 def _bf16(a) -> np.ndarray:
     import ml_dtypes
     return np.asarray(a, ml_dtypes.bfloat16).astype(np.float64)
+
+
+def _host_bytes(tree) -> int:
+    """Bytes of the distinct arrays among a tree's leaves."""
+    import jax
+    seen = {id(x): x for x in jax.tree.leaves(tree) if hasattr(x, "nbytes")}
+    return sum(int(x.nbytes) for x in seen.values())
 
 
 class CellBase:
@@ -65,18 +81,23 @@ class CellBase:
         self.ref = reference
         self.step_program = config["step_program"]
         self.rng = np.random.default_rng([seed, 5])
-        self.capture = StepCapture(self._unpack)
+        self.capture = StepCapture(self)
         self.migs: List[Dict[str, Any]] = []
+        self.mig_capacity: Optional[int] = None
         self.n_migs_seen = 0
-        self.fold: Optional[Dict[str, Any]] = None
+        self.fold: Optional[Dict[str, float]] = None
 
     @staticmethod
-    def _unpack(args, outs):
-        """A split train step's (dev, srv, dev_opt, srv_opt, batch, lr)
-        and its (dev, srv, dev_opt, srv_opt, loss, ...)."""
-        return {"params": (args[0], args[1]), "batch": args[4],
-                "new_params": (outs[0], outs[1]),
-                "new_opt": (outs[2], outs[3]), "loss": outs[4]}
+    def step_inputs(args):
+        """A split train step's (dev, srv, dev_opt, srv_opt, batch, lr):
+        its parameters and its batch."""
+        return (args[0], args[1]), args[4]
+
+    @staticmethod
+    def step_outputs(outs):
+        """Its (dev, srv, dev_opt, srv_opt, loss, ...): new parameters,
+        new optimizer state, loss."""
+        return (outs[0], outs[1]), (outs[2], outs[3]), outs[4]
 
     def merge(self, dev, srv):
         raise NotImplementedError("a driver's Cell gives merge(dev, srv)")
@@ -84,65 +105,80 @@ class CellBase:
     def first_grad(self, opt_state):
         raise NotImplementedError("a driver's Cell gives first_grad(state)")
 
-    def sample_migration(self, ckpt, base, restored, quant=None) -> None:
-        """``quant``: (leaves, bases, codes, scales) of the packed
-        quantize this migration ran, if it ran one."""
+    def host_leaves(self, pair) -> List[np.ndarray]:
+        """The full tree of a (dev, srv) pair as host arrays: both stages
+        are copied to the host first and merged there."""
+        import jax
+        dev, srv = (jax.tree.map(np.asarray, t) for t in pair)
+        with jax.default_device(jax.devices("cpu")[0]):
+            return [np.asarray(x) for x in jax.tree.leaves(
+                self.merge(dev, srv))]
+
+    def sample_migration(self, sent, base, restored, quant=None) -> None:
+        """``sent``: the host tree the move packed (``to_tree``);
+        ``restored``: the restored checkpoint's fields; ``quant``:
+        (leaves, bases, codes, scales) of the packed quantize this
+        migration ran, if it ran one. All host data."""
         self.n_migs_seen += 1
-        rec = {"sent": ckpt, "base": base, "restored": restored,
+        rec = {"sent": sent, "base": base, "restored": restored,
                "quant": quant}
-        if len(self.migs) < SAMPLED_MIGRATIONS:
+        if self.mig_capacity is None:
+            self.mig_capacity = max(1, min(
+                SAMPLED_MIGRATIONS,
+                MIGRATION_SAMPLE_BYTES // max(_host_bytes(rec), 1)))
+        if len(self.migs) < self.mig_capacity:
             self.migs.append(rec)
             return
         j = int(self.rng.integers(0, self.n_migs_seen))
-        if j < SAMPLED_MIGRATIONS:
+        if j < self.mig_capacity:
             self.migs[j] = rec
 
     # -- the numbers --------------------------------------------------------
-
-    def _streams(self):
-        return [self.capture.streams[k] for k in sorted(self.capture.streams)]
 
     def training_readings(self, mode: str = "program") -> Dict[str, float]:
         """Readings of the program (``program``), or of the reference put
         in its place in bfloat16 (``control``) or over half of each batch
         (``half_batch``), against the float32 reference at the stated
         precision and at ``HIGHEST``."""
-        streams = self._streams()
+        batches = self.capture.batches()
         out: Dict[str, float] = {}
         for precision, suffix in PRECISIONS:
             ref = reference_readings(self.ref, self.config, self.seed,
-                                     streams, precision)
+                                     batches, precision)
             if mode == "program":
-                got = program_readings(streams, self.merge,
-                                       self.first_grad)
+                got = self.capture.readings()
             else:
                 got = reference_readings(
-                    self.ref, self.config, self.seed, streams, precision,
+                    self.ref, self.config, self.seed, batches, precision,
                     control=mode == "control",
                     half_batch=mode == "half_batch")
             out.update(check.training_numbers(got, ref, suffix))
         return out
 
+    def take_fold(self, trees: List[List[np.ndarray]], weights,
+                  out: List[np.ndarray]) -> None:
+        """Reduce one fold to its numbers: ``out``, the committed fold of
+        ``trees`` (host leaves) by ``weights``, against a float64 numpy
+        fold of the same inputs, leaf by leaf; the control folds
+        bfloat16-rounded inputs instead."""
+        def ref(cast=lambda x: x):
+            for j in range(len(trees[0])):
+                yield check.fedavg_leaf([cast(t[j]) for t in trees], weights)
+        self.fold = {"program": check.fold_err(out, ref()),
+                     "control": check.fold_err(
+                         (_bf16(x) for x in ref(_bf16)), ref())}
+
     def fold_number(self, control: bool = False) -> float:
-        """The committed fold against a float64 numpy fold of the same
-        inputs; ``control`` folds bfloat16-rounded inputs instead."""
-        f = self.fold
-        if f is None:
+        """``fold_err`` of the fold ``take_fold`` kept, or its control's."""
+        if self.fold is None:
             return math.inf
-        trees = [_leaves(t) for t in f["trees"]]
-        ref = check.fedavg_ref(trees, f["weights"])
-        if control:
-            out = [_bf16(x) for x in check.fedavg_ref(
-                [[_bf16(x) for x in t] for t in trees], f["weights"])]
-        else:
-            out = _leaves(f["out"])
-        floats = [i for i, x in enumerate(ref) if x.dtype.kind == "f"]
-        return check.fold_err([out[i] for i in floats],
-                              [ref[i] for i in floats])
+        return self.fold["control" if control else "program"]
 
     def _mig_leaves(self, m):
         import jax
-        sent, rest = m["sent"].to_tree(), m["restored"].to_tree()
+        from repro.core.checkpoint import EdgeCheckpoint
+        sent = m["sent"]
+        rest = EdgeCheckpoint(**m["restored"]).to_tree()
         s_l, r_l, b_l = [], [], []
         for k in CHECKPOINT_PARTS:
             if k not in sent:

@@ -17,8 +17,10 @@ its limit from ``limits/<workload>.json``. The numbers:
                 the median leaf's are left out (they move by round-off).
 ``*_f32``       the same three against the reference with every product
                 at ``HIGHEST``: float32 as written.
-``fold_err``    the committed global model against a float64 numpy fold
-                of the same updates: worst leaf of max|diff| / max|ref|.
+``fold_err``    the committed global model of the warm-up round, the
+                window's fold program at its shapes, against a float64
+                numpy fold of the same updates, leaf by leaf: worst leaf
+                of max|diff| / max|ref|.
 ``codec_err``   a migrated state as restored against the state sent:
                 worst |restored - sent| over half the codec's quantization
                 step (plus two float32 spacings of the value). 1 is the
@@ -33,7 +35,7 @@ its limit from ``limits/<workload>.json``. The numbers:
 from __future__ import annotations
 
 import math
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -46,16 +48,17 @@ NEGLIGIBLE_GRAD = 1e-3
 TIE_TOL = 1e-4
 
 
-def _norms(leaves: Sequence[np.ndarray]) -> np.ndarray:
-    """(R, L) float64 norms per client and leaf; leaves are (R, ...)."""
-    return np.stack([np.sqrt(np.sum(np.square(
-        np.asarray(x, np.float64).reshape(x.shape[0], -1)), axis=1))
-        for x in leaves], axis=1)
+def leaf_norms(leaves: Iterable[Any]) -> np.ndarray:
+    """(L,) float64 norm of each leaf, one leaf at a time: ``leaves``
+    may be a generator, so no whole tree is held in float64."""
+    return np.array([np.sqrt(np.sum(np.square(
+        np.asarray(x, np.float64).reshape(-1)))) for x in leaves])
 
 
-def norm_gap(prog: Sequence[np.ndarray], ref: Sequence[np.ndarray],
+def norm_gap(prog: np.ndarray, ref: np.ndarray,
              keep: Optional[np.ndarray] = None) -> float:
-    a, r = _norms(prog), _norms(ref)
+    """``prog``/``ref``: (R, L) leaf norms per client."""
+    a, r = np.asarray(prog, np.float64), np.asarray(ref, np.float64)
     med = np.median(r, axis=1, keepdims=True)
     gap = np.abs(a - r) / np.maximum(np.maximum(r, med), 1e-30)
     if keep is not None:
@@ -65,11 +68,11 @@ def norm_gap(prog: Sequence[np.ndarray], ref: Sequence[np.ndarray],
 
 def training_numbers(prog: Dict[str, Any], ref: Dict[str, Any],
                      suffix: str = "") -> Dict[str, float]:
-    """``prog``/``ref``: ``losses`` (S, R), ``g1`` and ``delta`` lists of
-    (R, ...) leaves in one order."""
+    """``prog``/``ref``: ``losses`` (S, R), ``g1`` and ``delta`` (R, L)
+    leaf norms (``leaf_norms``), leaves in one order."""
     lp = np.asarray(prog["losses"], np.float64)
     lr = np.asarray(ref["losses"], np.float64)
-    g_ref = _norms(ref["g1"])
+    g_ref = np.asarray(ref["g1"], np.float64)
     keep = g_ref >= NEGLIGIBLE_GRAD * np.median(g_ref, axis=1,
                                                 keepdims=True)
     return {
@@ -79,7 +82,9 @@ def training_numbers(prog: Dict[str, Any], ref: Dict[str, Any],
     }
 
 
-def fold_err(prog: Sequence[np.ndarray], ref: Sequence[np.ndarray]) -> float:
+def fold_err(prog: Iterable[np.ndarray], ref: Iterable[np.ndarray]
+             ) -> float:
+    """Worst leaf of max|diff| / max|ref|, one leaf at a time."""
     out = 0.0
     for a, r in zip(prog, ref):
         a = np.asarray(a, np.float64)
@@ -89,13 +94,12 @@ def fold_err(prog: Sequence[np.ndarray], ref: Sequence[np.ndarray]) -> float:
     return out
 
 
-def fedavg_ref(trees: Sequence[Sequence[np.ndarray]],
-               weights: Sequence[float]) -> List[np.ndarray]:
-    """Dataset-size weighted mean, float64."""
+def fedavg_leaf(xs: Sequence[np.ndarray], weights: Sequence[float]
+                ) -> np.ndarray:
+    """One leaf's dataset-size weighted mean over the clients, float64."""
     w = np.asarray(weights, np.float64)
     w = w / w.sum()
-    return [sum(wi * np.asarray(t[j], np.float64) for wi, t in zip(w, trees))
-            for j in range(len(trees[0]))]
+    return sum(wi * np.asarray(x, np.float64) for wi, x in zip(w, xs))
 
 
 def _half_step_bound(sent: np.ndarray, scale: np.ndarray) -> np.ndarray:

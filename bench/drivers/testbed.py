@@ -7,14 +7,20 @@ migration codec. One ``Cell`` is built per run:
 ``setup``   makes the data from the seed, builds the scheduler, and
             trains round 0 as warm-up. Round 0 compiles every program
             the window uses (the split step, the fold, the codec) and
-            feeds the training check: the first three steps of every
-            client go through the scheduler's own step and feed.
+            feeds the training check and the fold check: the first three
+            steps of every client go through the scheduler's own step
+            and feed, and round 0's fold is the window's program at the
+            window's shapes. Both are copied to the host and reduced to
+            the numbers compared there.
 ``window``  runs whole rounds (``run_round``) until ``seconds`` have
             passed; each round ends in a host read of the folded model.
-``numbers`` after the window: the training check, one fold of the
-            window against a numpy fold, and a sample of the window's
-            migrations against the codec's bound, with the packed
-            quantize's codes and scales against the numpy reference.
+            Its migrations are sampled from the host trees the moves
+            themselves fetch and restore, so the window copies nothing
+            more.
+``numbers`` after the window: the training check, round 0's fold
+            numbers, and the migration sample against the
+            codec's bound, with the packed quantize's codes and scales
+            against the numpy reference.
 
 What depends on the model are hooks, VGG-5's by default:
 
@@ -35,9 +41,12 @@ stay here.
 """
 from __future__ import annotations
 
+import dataclasses
 import math
 import time
 from typing import Any, Dict, List, Optional
+
+import numpy as np
 
 import data
 from cellbase import CellBase
@@ -54,10 +63,10 @@ class Cell(CellBase):
     def __init__(self, *args, **kw):
         super().__init__(*args, **kw)
         self.stalls: List[float] = []
-        self.fold_round = 1          # the window's first fold
         self._client = None
         self._move_t0: Optional[float] = None
         self._quant = None
+        self._sent = None
         self._unpatch = lambda: None
         self.round = 0
 
@@ -146,9 +155,10 @@ class Cell(CellBase):
 
         def step_w(*args):
             self.tick(bs)
+            self.capture.before(self._client, args)
             with spans.span("step"):
                 outs = step(*args)
-            self.capture.record(self._client, args, outs)
+            self.capture.record(self._client, outs)
             return outs
         sched._step = step_w
 
@@ -177,12 +187,25 @@ class Cell(CellBase):
         def migrate_w(ckpt, src, dst, **kw):
             base = (sched.base_registry.base_for(dst)[0]
                     if sched.base_registry is not None else None)
-            self._quant = None
+            self._quant = self._sent = None
             restored, report = migrate(ckpt, src, dst, **kw)
             if self.round > 0:           # the window's, not warm-up's
-                self.sample_migration(ckpt, base, restored, self._quant)
+                self.sample_migration(
+                    self._sent, base,
+                    {f.name: getattr(restored, f.name)
+                     for f in dataclasses.fields(restored)}, self._quant)
+            self._sent = None
             return restored, report
         sched.migrator.migrate = migrate_w
+
+        # the host tree the move's own device-to-host fetch made
+        from repro.core.checkpoint import EdgeCheckpoint
+        to_tree = EdgeCheckpoint.to_tree
+
+        def to_tree_w(ckpt):
+            self._sent = to_tree(ckpt)
+            return self._sent
+        EdgeCheckpoint.to_tree = to_tree_w
 
         # the packed quantize's inputs and outputs, as the codec ran it
         from repro.kernels.int8_codec import ops as codec_ops
@@ -197,24 +220,27 @@ class Cell(CellBase):
 
         def unpatch():
             codec_ops.quantize_leaves = quantize
+            EdgeCheckpoint.to_tree = to_tree
+            self._unpatch = lambda: None
         self._unpatch = unpatch
 
         aggregate = sched._aggregate
 
         def aggregate_w():
-            take = self.round == self.fold_round
+            take = self.fold is None     # round 0's, in warm-up
             if take:
                 trees, weights = [], []
                 for dev in sched.devices.values():
                     st = sched.edges[dev.edge_id].clients[dev.client_id]
-                    trees.append(_tree_leaves(self.merge(dev.dev_params,
-                                                         st.srv_params)))
+                    trees.append(self.host_leaves((dev.dev_params,
+                                                   st.srv_params)))
                     weights.append(dev.num_samples)
             with spans.span("aggregate"):
                 aggregate()
             if take:
-                self.fold = {"trees": trees, "weights": weights,
-                             "out": _tree_leaves(sched.global_params)}
+                self.take_fold(trees, weights,
+                               [np.asarray(x) for x in
+                                _tree_leaves(sched.global_params)])
         sched._aggregate = aggregate_w
 
     # -- run --------------------------------------------------------------
@@ -243,7 +269,7 @@ class Cell(CellBase):
             for k, v in self.run_rounds(1).items():
                 totals[k] += v
             elapsed = time.perf_counter() - t0
-            if elapsed >= seconds and self.fold is not None:
+            if elapsed >= seconds:
                 break
         totals.update(elapsed_s=elapsed,
                       samples=totals["batches"] * self.config["batch_size"],
@@ -251,6 +277,8 @@ class Cell(CellBase):
         return totals
 
     def release(self) -> None:
-        """Drop the program's state before the reference runs."""
+        """Drop the program's state before the reference runs, and give
+        the program's classes back their own methods; a second call does
+        nothing more."""
         self._unpatch()
         self.sched = None

@@ -10,10 +10,11 @@ traffic mix (``bench/traffic/<name>.json``). Set-up runs from process
 start to the first timed batch: data from the seed, the system, and a
 warm-up round that compiles every program the window uses. The window
 then runs whole rounds for ``--seconds``. After it, the program's state
-is freed and what the window produced is compared with the plain
-reference (``bench/refs/<reference>.py``, named by the configuration,
-which also counts the training FLOPs a sample); every number compared is
-printed beside its limit (``bench/limits/<cell>.json``).
+is freed and what warm-up and the window produced, kept on the host, is
+compared with the plain reference (``bench/refs/<reference>.py``, named
+by the configuration, which also counts the training FLOPs a sample);
+every number compared is printed beside its limit
+(``bench/limits/<cell>.json``).
 
 ``--trace 0`` reports the cell's end-to-end metrics. ``--trace 1``
 profiles a slice of a few seconds inside the window, with the program's
@@ -198,6 +199,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     if trace:
         from repro.obs import telemetry
         telemetry.enable()
+    cell = None
     try:
         drv = harness.driver(config["driver"], root)
         ref = harness.reference(config["reference"], root)
@@ -286,6 +288,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
             "seconds": stats["elapsed_s"], "samples": stats["samples"],
             "batches": stats["batches"],
             "migrations": stats["migrations"],
+            "migrations_checked": len(cell.migs),
             "stalls": len(stats["stalls_s"]),
             "compiles_in_window": compiles, "setup_s": setup_s, **rates}
         if judged:
@@ -293,6 +296,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         result["checks"] = verdict["checks"]
         return result
     finally:
+        if cell is not None:
+            cell.release()
         if telemetry is not None:
             telemetry.disable()
         if tmp:

@@ -2,10 +2,13 @@
 on the CPU, and helpers to plant faults underneath the timed path."""
 from __future__ import annotations
 
+import json
+import shutil
 import sys
 from pathlib import Path
 
 BENCH = Path(__file__).resolve().parents[1]
+DATA = BENCH / "tests" / "data"
 for p in (BENCH, BENCH.parent / "src"):
     if str(p) not in sys.path:
         sys.path.insert(0, str(p))
@@ -84,3 +87,45 @@ def altered_unpack(monkeypatch):
         return ck.replace(server_params=scaled(ck.server_params,
                                                1.1))
     monkeypatch.setattr(EdgeCheckpoint, "unpack", classmethod(broken))
+
+
+def add_token_config(root: Path, name: str) -> str:
+    """Add the test-only token configuration ``tests/data/<name>/`` to
+    the checkout at ``root`` as new files only, with tiny-lm's driver and
+    reference, and a cell ``<name>-handoff`` in its ``BENCHMARK.json``;
+    returns the cell's name."""
+    cell = f"{name}-handoff"
+    files = {DATA / name / "config.json": f"configs/{name}.json",
+             DATA / name / "traffic.json": f"traffic/{cell}.json",
+             DATA / name / "limits.json": f"limits/{cell}.json",
+             DATA / "tiny-lm" / "driver.py": "drivers/tinylm.py",
+             DATA / "tiny-lm" / "reference.py": "refs/tinylm.py"}
+    for src, dst in files.items():
+        if (root / "bench" / dst).exists():
+            raise FileExistsError(f"the checkout already has {dst}")
+        shutil.copy(src, root / "bench" / dst)
+    config = json.loads((DATA / name / "config.json").read_text())
+    spec = json.loads((root / "BENCHMARK.json").read_text())
+    spec["configs"].append({
+        "name": name, "source": "https://arxiv.org/abs/2403.04652",
+        "file": f"bench/configs/{name}.json",
+        "reduced": config["reduced"],
+        "why": "test-only: a token model through FedFlyScheduler"})
+    spec["workloads"].append({
+        "name": cell, "config": name, "traffic": cell, "chips": 1,
+        "why": "test-only: one handoff a round, raw codec"})
+    (root / "BENCHMARK.json").write_text(json.dumps(spec))
+    return cell
+
+
+def keep_cells(monkeypatch):
+    """The list to which every cell is added as its check starts."""
+    import cellbase
+    cells = []
+    numbers = cellbase.CellBase.numbers
+
+    def kept(self):
+        cells.append(self)
+        return numbers(self)
+    monkeypatch.setattr(cellbase.CellBase, "numbers", kept)
+    return cells

@@ -37,8 +37,13 @@ class TokenBatcher:
 
 class Cell(testbed.Cell):
     def _arch(self):
+        """The registry's reduced size, or the configuration's ``sizes``
+        where it gives them."""
         from repro.models import registry
-        return registry.make_reduced(registry.get_config(self.config["arch"]))
+        arch = registry.get_config(self.config["arch"])
+        sizes = self.config.get("sizes")
+        return arch.replace(**sizes) if sizes else registry.make_reduced(
+            arch)
 
     def check_config(self) -> None:
         arch = self._arch()

@@ -20,9 +20,12 @@ import jax.numpy as jnp
 
 
 def _model(config: Dict[str, Any]):
+    """The registry's reduced size, or the configuration's ``sizes``."""
     from repro.models import registry
+    arch = registry.get_config(config["arch"])
+    sizes = config.get("sizes")
     return registry.build_model(
-        registry.make_reduced(registry.get_config(config["arch"])))
+        arch.replace(**sizes) if sizes else registry.make_reduced(arch))
 
 
 def init(seed: int, config: Dict[str, Any]):

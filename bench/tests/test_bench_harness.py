@@ -21,15 +21,6 @@ sys.path.insert(0, str(BENCH))
 import flops  # noqa: E402
 import harness  # noqa: E402
 
-TINY_LM = BENCH / "tests" / "data" / "tiny-lm"
-#: the tiny token model's files, and where a configuration puts them
-TINY_LM_FILES = {"config.json": "configs/tiny-lm.json",
-                 "driver.py": "drivers/tinylm.py",
-                 "reference.py": "refs/tinylm.py",
-                 "traffic.json": "traffic/tokens-handoff.json",
-                 "limits.json": "limits/tiny-lm-handoff.json"}
-
-
 def test_vgg5_forward_flops_from_shapes():
     cfg = harness.load_json(BENCH / "configs" / "vgg5-testbed.json")
     totals = [0] + [flops.forward_per_sample(cfg["layers"][:i + 1],
@@ -101,24 +92,13 @@ def _bench_files(root):
 def tiny_lm_checkout(tmp_path_factory):
     """A copy of the benchmark with a token model added as new files
     only, and the bytes of every file the copy had before."""
+    from _small import add_token_config
     root = tmp_path_factory.mktemp("tiny-lm") / "checkout"
     shutil.copytree(BENCH, root / "bench",
                     ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copy(ROOT / "BENCHMARK.json", root / "BENCHMARK.json")
     before = _bench_files(root)
-    for src, dst in TINY_LM_FILES.items():
-        assert not (root / "bench" / dst).exists()
-        shutil.copy(TINY_LM / src, root / "bench" / dst)
-    spec = json.loads((ROOT / "BENCHMARK.json").read_text())
-    spec["configs"].append({
-        "name": "tiny-lm", "source": "https://arxiv.org/abs/2403.04652",
-        "file": "bench/configs/tiny-lm.json",
-        "reduced": ["num_layers", "d_model", "vocab_size"],
-        "why": "test-only: a token model through FedFlyScheduler"})
-    spec["workloads"].append({
-        "name": "tiny-lm-handoff", "config": "tiny-lm",
-        "traffic": "tokens-handoff", "chips": 1,
-        "why": "test-only: one handoff a round, raw codec"})
-    (root / "BENCHMARK.json").write_text(json.dumps(spec))
+    assert add_token_config(root, "tiny-lm") == "tiny-lm-handoff"
     return root, before
 
 
@@ -162,6 +142,24 @@ def test_a_new_configurations_check_catches_half_a_batch(
     assert {p: after[p] for p in before} == before
 
 
+def test_a_new_configurations_check_holds_no_device_array(
+        tiny_lm_checkout, monkeypatch):
+    import jax
+    from _small import keep_cells
+    root, _ = tiny_lm_checkout
+    cells = keep_cells(monkeypatch)
+    r = _run_tiny_lm(root)
+    assert r["correct"], r["checks"]
+    [cell] = cells
+    held = {"capture": cell.capture.streams, "fold": cell.fold,
+            "migrations": cell.migs}
+    for part, tree in held.items():
+        leaves = jax.tree.leaves(tree)
+        assert leaves, part
+        assert not [x for x in leaves if isinstance(x, jax.Array)], part
+    assert r["window"]["migrations_checked"] == len(cell.migs) >= 1
+
+
 def test_the_reference_counts_vgg5_training_flops():
     cfg = harness.load_json(BENCH / "configs" / "vgg5-testbed.json")
     ref = harness.reference(cfg["reference"])
@@ -183,8 +181,8 @@ def test_reference_readings_stack_every_key_of_the_batch():
     ref = types.SimpleNamespace(
         __name__="keys_probe", train_steps=train_steps,
         init=lambda seed, config: {"w": np.ones(4, np.float32)})
-    streams = [[{"batch": {"frames": np.full((6, 3), c, np.float32),
-                           "ids": np.arange(6, dtype=np.int32)}}
+    streams = [[{"frames": np.full((6, 3), c, np.float32),
+                 "ids": np.arange(6, dtype=np.int32)}
                 for _ in range(steps.STEPS)] for c in range(2)]
     out = steps.reference_readings(ref, {}, 0, streams)
     steps.reference_readings(ref, {}, 0, streams, half_batch=True)
