@@ -13,18 +13,25 @@ Backend selection mirrors ``fedavg_agg``: ``use_pallas``/``interpret``
 default to ``None`` = auto-detect — compiled Pallas on TPU/GPU, the
 pure-numpy reference on CPU (never the interpreter's python grid loop
 on the production path).
+
+On the Pallas path the host pads: the payload and its base are
+scattered into numpy buffers of ``int8_codec.padded_len(n)`` elements,
+which the codec lays out as its kernels' grid without a copy, and codes,
+scales and decoded values come back as grids trimmed on the host. The
+packed programs are built once per padded length and reused by every
+later payload of that length.
 """
 from __future__ import annotations
 
 from typing import List, Optional, Sequence, Tuple
 
-import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.int8_codec.int8_codec import (BLOCK, ROWS,
-                                                 dequantize_packed,
+from repro.kernels.int8_codec.int8_codec import (BLOCK, dequantize_packed,
+                                                 dequantize_tiled,
                                                  has_compiled_pallas,
-                                                 quantize_packed)
+                                                 padded_len, quantize_packed,
+                                                 quantize_tiled)
 from repro.kernels.int8_codec.ref import (dequantize_packed_ref,
                                           dequantize_ref,
                                           quantize_packed_ref, quantize_ref)
@@ -52,6 +59,16 @@ def leaf_offsets(leaves: Sequence[np.ndarray]) -> np.ndarray:
     return starts
 
 
+def _scatter(flat: np.ndarray, leaves, offsets: np.ndarray) -> np.ndarray:
+    """Write each leaf into ``flat`` at its offset; a ``None`` leaf
+    leaves its span zero."""
+    for i, x in enumerate(leaves):
+        if x is not None:
+            arr = np.asarray(x, np.float32).reshape(-1)
+            flat[offsets[i]:offsets[i] + arr.size] = arr
+    return flat
+
+
 def pack_leaves(leaves: Sequence[np.ndarray]
                 ) -> Tuple[np.ndarray, np.ndarray]:
     """Concatenate float leaves into one f32 flat buffer; returns
@@ -63,11 +80,8 @@ def pack_leaves(leaves: Sequence[np.ndarray]
     padding costs < BLOCK elements per leaf — noise against multi-MB
     checkpoint payloads."""
     starts = leaf_offsets(leaves)
-    flat = np.zeros(int(starts[-1]), np.float32)
-    for i, x in enumerate(leaves):
-        arr = np.asarray(x, np.float32).reshape(-1)
-        flat[starts[i]:starts[i] + arr.size] = arr
-    return flat, starts
+    return _scatter(np.zeros(int(starts[-1]), np.float32), leaves,
+                    starts), starts
 
 
 def quantize_leaves(leaves: Sequence[np.ndarray],
@@ -82,24 +96,20 @@ def quantize_leaves(leaves: Sequence[np.ndarray],
     ``base_leaves``, residuals are quantized; a ``None`` base entry means
     a zero base for that leaf (plain blockwise int8).
     """
-    flat, offsets = pack_leaves(leaves)
-    n = flat.shape[0]
-    base_flat = None
-    if base_leaves is not None:
-        base_flat = np.zeros_like(flat)
-        for i, b in enumerate(base_leaves):
-            if b is not None:
-                arr = np.asarray(b, np.float32).reshape(-1)
-                base_flat[offsets[i]:offsets[i] + arr.size] = arr
+    pallas = _resolve_use_pallas(use_pallas)
+    offsets = leaf_offsets(leaves)
+    n = int(offsets[-1])
+    size = padded_len(n) if pallas else n
+    flat = _scatter(np.zeros(size, np.float32), leaves, offsets)
+    base_flat = (None if base_leaves is None else
+                 _scatter(np.zeros_like(flat), base_leaves, offsets))
     if n == 0:
         return (np.zeros((0,), np.int8), np.zeros((0,), np.float32),
                 offsets)
-    if _resolve_use_pallas(use_pallas):
-        q, s = quantize_packed(
-            jnp.asarray(flat),
-            None if base_flat is None else jnp.asarray(base_flat),
-            interpret=interpret)
-        return (np.asarray(q)[:n], np.asarray(s)[:num_scales(n)], offsets)
+    if pallas:
+        q, s = quantize_tiled(flat, base_flat, interpret=interpret)
+        return (np.asarray(q).reshape(-1)[:n],
+                np.asarray(s).reshape(-1)[:num_scales(n)], offsets)
     q, s = quantize_packed_ref(flat, base_flat)
     return q, s, offsets
 
@@ -115,20 +125,15 @@ def dequantize_leaves(q: np.ndarray, scales: np.ndarray,
     """Inverse of ``quantize_leaves``: one dispatch, then slice per leaf
     by the offset table and cast to each leaf's dtype."""
     n = int(offsets[-1])
-    base_flat = None
-    if base_leaves is not None:
-        base_flat = np.zeros((n,), np.float32)
-        for i, b in enumerate(base_leaves):
-            if b is not None:
-                arr = np.asarray(b, np.float32).reshape(-1)
-                base_flat[offsets[i]:offsets[i] + arr.size] = arr
+    pallas = _resolve_use_pallas(use_pallas)
+    size = padded_len(n) if pallas else n
+    base_flat = (None if base_leaves is None else
+                 _scatter(np.zeros(size, np.float32), base_leaves, offsets))
     if n == 0:
         flat = np.zeros((0,), np.float32)
-    elif _resolve_use_pallas(use_pallas):
-        flat = np.asarray(dequantize_packed(
-            jnp.asarray(q[:n]), jnp.asarray(scales), n,
-            None if base_flat is None else jnp.asarray(base_flat),
-            interpret=interpret))
+    elif pallas:
+        flat = np.asarray(dequantize_tiled(
+            q[:n], scales, base_flat, interpret=interpret)).reshape(-1)[:n]
     else:
         flat = dequantize_packed_ref(q, scales, n, base_flat)
     out = []

@@ -19,6 +19,7 @@ from repro.kernels.int8_codec import (dequantize, dequantize_packed,
 from repro.kernels.int8_codec.ops import (dequantize_leaves, pack_leaves,
                                           quantize_leaves, roundtrip)
 from repro.kernels.wkv6 import wkv6, wkv6_ref
+from repro.obs import telemetry as obs
 
 
 # -- flash attention ---------------------------------------------------------
@@ -212,6 +213,79 @@ def test_quantize_packed_residual_matches_numpy_ref(n):
     assert float(jnp.max(jnp.abs(out - x))) <= bound
     out_np = dequantize_packed_ref(q_ref, s_ref, n, base)
     np.testing.assert_allclose(np.asarray(out), out_np, atol=1e-6)
+
+
+PACKED_NS = [1, 1000, 8191, 8192, 8193, 70_001]
+
+
+def _packed_inputs(n, residual):
+    rng = np.random.default_rng(n)
+    x = rng.normal(size=(n,)).astype(np.float32) * 4
+    base = (x + rng.normal(size=(n,)).astype(np.float32) * 0.01
+            if residual else None)
+    return x, base
+
+
+def _bits(a) -> np.ndarray:
+    a = np.asarray(a)
+    return a.view(np.int32) if a.dtype == np.float32 else a
+
+
+@pytest.mark.parametrize("residual", [False, True], ids=["plain", "residual"])
+@pytest.mark.parametrize("n", PACKED_NS)
+def test_packed_codec_matches_numpy_ref_and_eager(n, residual):
+    """The jitted packed programs (interpret) give the numpy reference's
+    codes, its scales within the one ulp the benchmark allows, and its
+    decoded values within one ulp of the larger operand (q*s + base may
+    round once); and every output bit-equal to the same kernels run
+    eagerly, outside jit, as they ran before they were jitted."""
+    x, base = _packed_inputs(n, residual)
+    q_ref, s_ref = quantize_packed_ref(x, base)
+    b = None if base is None else jnp.asarray(base)
+    q, s = quantize_packed(jnp.asarray(x), b, interpret=True)
+    out = dequantize_packed(jnp.asarray(q_ref), jnp.asarray(s_ref), n, b,
+                            interpret=True)
+    with jax.disable_jit():
+        eager = (*quantize_packed(jnp.asarray(x), b, interpret=True),
+                 dequantize_packed(jnp.asarray(q_ref), jnp.asarray(s_ref),
+                                   n, b, interpret=True))
+    for got, was in zip((q, s, out), eager):
+        np.testing.assert_array_equal(_bits(got), _bits(was))
+    np.testing.assert_array_equal(np.asarray(q)[:n], q_ref)
+    ulps = np.abs(_bits(np.asarray(s)[:len(s_ref)]) - _bits(s_ref))
+    assert int(ulps.max()) <= 1
+    out_ref = dequantize_packed_ref(q_ref, s_ref, n, base)
+    big = np.abs(out_ref) + (0 if base is None else np.abs(base))
+    assert out.shape == (n,)
+    assert np.all(np.abs(np.asarray(out) - out_ref) <= np.spacing(big))
+
+
+def _kernel_builds() -> float:
+    snap = obs.snapshot()
+    return 0 if snap is None else snap["counters"].get("codec.kernel_builds",
+                                                       0)
+
+
+@pytest.mark.parametrize("residual", [False, True], ids=["plain", "residual"])
+def test_packed_codec_built_once_per_padded_length(residual):
+    """A second payload of the same padded length, or of another length
+    in the same TILE bucket, reuses the programs the first one built."""
+    from repro.kernels.int8_codec import int8_codec
+    cores = (int8_codec._quantize_tiles, int8_codec._dequantize_tiles)
+    for core in cores:
+        core.clear_cache()
+    tile = int8_codec.TILE
+    obs.enable()
+    try:
+        for i, n in enumerate([3 * tile + 5, 3 * tile + 5, 4 * tile]):
+            x, base = _packed_inputs(n, residual)
+            b = None if base is None else jnp.asarray(base)
+            q, s = quantize_packed(jnp.asarray(x), b, interpret=True)
+            dequantize_packed(q, s, n, b, interpret=True).block_until_ready()
+            assert _kernel_builds() == (2 if i == 0 else 0), n
+    finally:
+        obs.disable()
+    assert [core._cache_size() for core in cores] == [1, 1]
 
 
 def test_pack_leaves_block_aligned_offsets():

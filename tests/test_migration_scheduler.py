@@ -155,3 +155,47 @@ def test_losses_decrease(small_batchers):
     first = np.mean(list(h.rounds[0].client_losses.values()))
     last = np.mean(list(h.rounds[-1].client_losses.values()))
     assert last < first
+
+
+def test_delta_migrations_reuse_the_codec_programs(monkeypatch):
+    """Two delta-codec moves of one checkpoint through the Pallas codec
+    build its quantize and dequantize programs once, on the first move,
+    and the second move restores bit-equal to the first."""
+    from repro.kernels.int8_codec import int8_codec, ops
+    from repro.obs import telemetry as obs
+
+    def on_pallas(fn):
+        return lambda *a, **kw: fn(*a, **{**kw, "use_pallas": True,
+                                          "interpret": True})
+    monkeypatch.setattr(ops, "quantize_leaves", on_pallas(ops.quantize_leaves))
+    monkeypatch.setattr(ops, "dequantize_leaves",
+                        on_pallas(ops.dequantize_leaves))
+    for core in (int8_codec._quantize_tiles, int8_codec._dequantize_tiles):
+        core.clear_cache()
+    rng = np.random.default_rng(0)
+    params = {"w": rng.normal(size=(3000,)).astype(np.float32),
+              "b": rng.normal(size=(96,)).astype(np.float32)}
+    ck = EdgeCheckpoint(
+        client_id="pi3_1", round_idx=1, epoch=1, batch_idx=4, split_point=2,
+        server_params=params,
+        optimizer_state={"mu": {k: v * 0.1 for k, v in params.items()}})
+    base = {"server_params": {k: v + np.float32(1e-3)
+                              for k, v in params.items()}}
+    ex = MigrationExecutor(codec="delta")
+    builds, restored = [], []
+    obs.enable()
+    try:
+        for _ in range(2):
+            out, _ = ex.migrate(ck, "edge-A", "edge-B", base=base,
+                                base_version="r1")
+            restored.append(out)
+            snap = obs.snapshot()
+            builds.append(snap["counters"].get("codec.kernel_builds", 0))
+    finally:
+        obs.disable()
+    assert builds == [2, 0]          # one quantize + one dequantize program
+    first, second = (jax.tree.leaves((r.server_params, r.optimizer_state))
+                     for r in restored)
+    assert all(np.array_equal(a, b) for a, b in zip(first, second))
+    err = np.abs(restored[0].server_params["w"] - params["w"]).max()
+    assert err <= 1e-3 / 127 * 0.51 + 1e-7

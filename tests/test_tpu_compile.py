@@ -16,6 +16,7 @@ written for a described chip cannot be read back without one.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -25,7 +26,8 @@ from jax.sharding import SingleDeviceSharding
 from repro.core import split as split_lib
 from repro.core.scheduler import FedFlyScheduler
 from repro.kernels.flash_attention import flash_attention
-from repro.kernels.int8_codec import dequantize_packed, quantize_packed
+from repro.kernels.int8_codec import (dequantize_packed, int8_codec,
+                                      quantize_packed)
 from repro.models.vgg import VGG5
 from repro.optim.optimizers import sgd
 from repro.optim.schedules import constant
@@ -93,6 +95,53 @@ def test_int8_codec_compiles(one_chip, n, residual):
     for fn in (quant, jax.jit(roundtrip)):
         text = fn.lower(*args).compile().as_text()
         assert "tpu_custom_call" in text
+        # the quantize stays its own op returning (codes, scales), the
+        # op the benchmark's int8_quantize_roofline reads in the trace
+        assert re.search(r" = \(s8\[[^\n]*\) custom-call\(", text)
+
+
+def _grid_values(text: str, grid: str):
+    """(opcode, type) of every instruction of a compiled module whose
+    result holds a value of the ``grid`` shape, e.g. ``[336,1024]``."""
+    out = []
+    for line in text.splitlines():
+        _, eq, rhs = line.partition(" = ")
+        if eq and grid in rhs.split(" metadata=")[0]:
+            op = re.search(r"[})] ([a-z][\w-]*)\(", rhs)
+            out.append((op.group(1) if op else rhs, rhs[:rhs.find(
+                op.group(0)) + 1] if op else rhs))
+    return out
+
+
+@pytest.mark.parametrize("residual", [False, True], ids=["plain", "delta"])
+@pytest.mark.parametrize("kind", ["quantize", "dequantize"])
+def test_int8_codec_kernel_reads_and_writes_hbm(one_chip, kind, residual):
+    """The packed programs the migration calls hold the kernel alone: its
+    (rows, BLOCK) operands are the program's parameters and its (rows,
+    BLOCK) result is the program's output, none staged through VMEM
+    (``S(1)``) or copied, so the kernel's device time covers the payload's
+    HBM traffic, as ``int8_quantize_roofline`` prices it."""
+    rt = int8_codec.padded_len(PAYLOAD) // int8_codec.BLOCK
+    grid = _on(one_chip, jax.ShapeDtypeStruct((rt, int8_codec.BLOCK),
+                                              jnp.float32))
+    base = grid if residual else None
+    if kind == "quantize":
+        lowered = int8_codec._quantize_tiles.lower(grid, base,
+                                                   interpret=False)
+    else:
+        codes = _on(one_chip, jax.ShapeDtypeStruct(grid.shape, jnp.int8))
+        scales = _on(one_chip, jax.ShapeDtypeStruct((rt, 1), jnp.float32))
+        lowered = int8_codec._dequantize_tiles.lower(
+            codes, scales, base, dtype=jnp.dtype(jnp.float32),
+            interpret=False)
+    values = _grid_values(lowered.compile().as_text(), f"[{rt},1024]")
+    ops = sorted({op for op, _ in values})
+    assert "custom-call" in ops
+    assert set(ops) <= {"parameter", "custom-call", "get-tuple-element",
+                        "tuple"}, ops
+    for op, typ in values:
+        grids = re.findall(rf"\w+\[{rt},1024\]\{{[^}}]*\}}", typ)
+        assert grids and not any("S(1)" in g for g in grids), (op, typ)
 
 
 def test_scheduler_split_step_compiles(one_chip):
